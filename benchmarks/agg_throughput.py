@@ -9,16 +9,19 @@ combine/propagate hot loops routed through the Pallas kernels).  Each
 configuration runs in a fresh subprocess so peak RSS (``ru_maxrss``) is
 honest — the parent's high-water mark can't leak between measurements.
 
-On a host without an accelerator the device rows run on the interpret-mode
-kernel proxy and are labeled ``device_mode: "interpret-proxy"`` — they
+On a host without an accelerator the device rows run the kernels in
+interpret mode and are labeled ``device_mode: "interpret-proxy"`` — they
 validate the full dispatch path and feed the parity gate, but their wall
 times are NOT accelerator performance.  Rows measured on real hardware are
-labeled ``device_mode: "accelerator"``.
+labeled ``device_mode: "accelerator"``.  Device rows run on the ``serial``
+and ``threads`` executors only: one process holds the accelerator, so this
+parent never imports JAX and each measurement subprocess is the only one
+on the device while it runs.
 
 Emits ``BENCH_agg.json`` with per-config wall time, profiles/sec, peak RSS
 the sharded path's peak out-of-order plane residency (``sink_peak``), and a
-``device_parity`` block: the device rows are re-run at 1, 2 and 4 shards
-and their PMS/CMS digests must collapse to a single set.
+``device_parity`` block: the device path is re-run serially and on 2 and 4
+threads, and its PMS/CMS digests must collapse to a single set.
 
 Standalone usage::
 
@@ -74,7 +77,7 @@ def _configs(smoke: bool, compute: str = "cpu"):
                     "plane_transport": transport,
                     "compute": "cpu",
                 })
-        if compute in ("device", "both"):
+        if compute in ("device", "both") and executor != "processes":
             cfgs.append({
                 "name": f"{executor}-device",
                 "executor": executor,
@@ -104,7 +107,7 @@ def _run_single(spec: dict) -> dict:
                             pipeline=spec["pipeline"],
                             plane_transport=spec["plane_transport"],
                             compute=spec.get("compute", "cpu"),
-                            # no accelerator -> interpret proxy, labeled below
+                            # no accelerator -> interpret mode, labeled below
                             device_interpret=True)
     t0 = time.perf_counter()
     res = StreamingAggregator(spec["out_dir"], cfg).run(paths)
@@ -122,7 +125,7 @@ def _run_single(spec: dict) -> dict:
         "n_values": res.n_values,
         "pms_bytes": res.sizes["pms"],
     }
-    if cfg.effective_compute() == "device":
+    if cfg.compute == "device":
         from repro.kernels import batch
         row["device_mode"] = ("accelerator" if batch.has_accelerator()
                               else "interpret-proxy")
@@ -148,12 +151,12 @@ def _spawn_single(spec: dict) -> dict:
 
 
 def _parity_gate(paths, td, out) -> dict:
-    """The device determinism gate: serial + processes device runs at 1, 2
-    and 4 shards must produce one (pms, cms) digest set."""
-    shards = [1, 2, 4]
+    """The device determinism gate: device runs on 1 (serial), 2 and 4
+    threads must produce one (pms, cms) digest set."""
+    workers = [1, 2, 4]
     digests = set()
-    for w in shards:
-        executor = "serial" if w == 1 else "processes"
+    for w in workers:
+        executor = "serial" if w == 1 else "threads"
         spec = {"name": f"parity-device-w{w}", "executor": executor,
                 "n_workers": w, "pipeline": "fused", "plane_transport": "shm",
                 "compute": "device", "paths": paths,
@@ -161,13 +164,13 @@ def _parity_gate(paths, td, out) -> dict:
         row = _spawn_single(spec)
         digests.add((row["pms_sha"], row["cms_sha"]))
     ok = len(digests) == 1
-    out(f"agg.device_parity,0,shards={'|'.join(map(str, shards))};"
+    out(f"agg.device_parity,0,workers={'|'.join(map(str, workers))};"
         f"ok={str(ok).lower()}")
     if not ok:
         raise AssertionError(
-            f"device path not shard-deterministic: {len(digests)} distinct "
-            f"digest sets across shard counts {shards}")
-    return {"shards": shards, "ok": ok}
+            f"device path not worker-count-deterministic: {len(digests)} distinct "
+            f"digest sets across worker counts {workers}")
+    return {"workers": workers, "ok": ok}
 
 
 def run(out=print, tiny: bool = False, check: bool = False,
@@ -208,6 +211,8 @@ def run(out=print, tiny: bool = False, check: bool = False,
     device_speedups = {}
     if compute == "both":
         for executor in EXECUTORS:
+            if f"{executor}-device" not in by_name:
+                continue
             fused = by_name[f"{executor}-fused"]
             device = by_name[f"{executor}-device"]
             device_speedups[executor] = fused["wall_s"] / device["wall_s"]

@@ -69,7 +69,10 @@ def build_app_tree(n_ctx: int, rng) -> ContextTree:
     return t
 
 
-def generate(w: Workload, out_dir: str, seed: int = 0) -> list[str]:
+def generate(w: Workload, out_dir: str, seed: int = 0,
+             counts: bool = False) -> list[str]:
+    """Write ``w``'s profiles under ``out_dir``; values are exponential
+    costs, or with ``counts`` small integer sample counts (1..15)."""
     os.makedirs(out_dir, exist_ok=True)
     rng = np.random.default_rng(seed)
     shared = build_app_tree(w.n_ctx, rng)
@@ -102,7 +105,8 @@ def generate(w: Workload, out_dir: str, seed: int = 0) -> list[str]:
                              replace=False)
             ctxs.extend([c] * len(sel))
             mids.extend(sel.tolist())
-            vals.extend(rng.exponential(1.0, len(sel)).tolist())
+            vals.extend((rng.integers(1, 16, len(sel)) if counts
+                         else rng.exponential(1.0, len(sel))).tolist())
         sm = SparseMetrics.from_triplets(ctxs, mids, vals)
         trace = Trace.empty()
         if w.trace_len:

@@ -62,7 +62,7 @@ def main():
 
     compiled = jax.jit(make_train_step(model, AdamWConfig())).lower(
         params, opt, {"tokens": jnp.asarray(pipe.batch_at(0))}).compile()
-    ca = compiled.cost_analysis() or {}
+    ca = compiled.cost_analysis()
     profs[1].attribute_compiled(
         compiled.as_text(), measured={"flops": ca.get("flops", 0.0)},
         struct_dir=os.path.join(args.out, "structs"))

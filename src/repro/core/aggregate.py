@@ -88,15 +88,14 @@ class AggregationConfig:
                                          # back to one-shot segments
     compute: str = "cpu"                 # "cpu" numpy hot loops, or "device"
                                          # — route phase-2 propagation /
-                                         # combine / CMS scans through the
-                                         # Pallas kernels (ROADMAP item 3);
-                                         # falls back to cpu when no
-                                         # accelerator is attached
-    device_interpret: bool = False       # let compute="device" run on the
-                                         # interpret-mode kernel proxy when
-                                         # no accelerator exists (tests /
-                                         # benches; slow, but exercises the
-                                         # real kernel bodies)
+                                         # combine / CMS census and scans
+                                         # through the Pallas kernels, in
+                                         # the one process that holds the
+                                         # accelerator; never falls back
+    device_interpret: bool = False       # let compute="device" run the
+                                         # kernels in interpret mode on the
+                                         # CPU backend (tests; slow, but
+                                         # exercises the real kernel bodies)
     stats_merge: str = "auto"            # cross-profile stats carry-chain:
                                          # "inline" on the consume thread,
                                          # "workers" on a small merge pool
@@ -107,15 +106,36 @@ class AggregationConfig:
     def workers(self) -> int:
         return max(1, self.n_threads if self.n_workers is None else self.n_workers)
 
-    def effective_compute(self) -> str:
-        """The backend that will actually run: ``"device"`` only when the
-        kernels can execute here (accelerator attached, or the interpret
-        proxy explicitly allowed) — otherwise silently ``"cpu"``, so one
-        config deploys unchanged on accelerator and plain hosts."""
-        if self.compute != "device":
-            return "cpu"
-        from repro.kernels import batch
-        return "device" if batch.device_ok(self.device_interpret) else "cpu"
+    def __post_init__(self):
+        if self.compute not in ("cpu", "device"):
+            raise ValueError(f"unknown compute {self.compute!r}; "
+                             f"expected 'cpu' or 'device'")
+        if self.compute == "device":
+            self._check_device()
+
+    def _check_device(self) -> None:
+        """``compute="device"`` runs where it was asked to or not at all:
+        on the fused pipeline, in the one process that holds the
+        accelerator, and on the CPU backend only with the explicit
+        ``device_interpret`` opt-in."""
+        if self.pipeline == "legacy":
+            raise ValueError("compute='device' requires pipeline='fused'; "
+                             "the legacy three-pass chain has no device path")
+        if self.executor in ("processes", "ranks"):
+            raise ValueError(
+                f"compute='device' cannot run under executor="
+                f"{self.executor!r}: one process holds the accelerator, and "
+                f"{self.executor!r} starts workers that would each open it; "
+                f"use executor='serial' or 'threads'")
+        import jax
+        platform = jax.default_backend()
+        if platform == "cpu" and not self.device_interpret:
+            raise RuntimeError(
+                f"compute='device' found no accelerator: JAX's platform is "
+                f"{platform!r} (devices: {jax.devices()}). Run on a host "
+                f"with a TPU, pass device_interpret=True "
+                f"(--device-interpret) to run the kernels in interpret mode "
+                f"on the CPU, or use compute='cpu'")
 
     def resolved_stats_merge(self) -> str:
         if self.stats_merge != "auto":
@@ -276,17 +296,6 @@ class StreamingAggregator:
         os.makedirs(self.out_dir, exist_ok=True)
         self.cfg = config or AggregationConfig()
 
-    def _executor(self):
-        kwargs = {}
-        if (self.cfg.executor == "processes"
-                and self.cfg.effective_compute() == "device"
-                and not os.environ.get("REPRO_MP_CONTEXT")):
-            # forking after XLA initializes in the parent can deadlock the
-            # children; spawn workers get a clean runtime.  An explicit
-            # REPRO_MP_CONTEXT still wins.
-            kwargs["mp_context"] = "spawn"
-        return get_executor(self.cfg.executor, self.cfg.workers, **kwargs)
-
     # -- phase 1: contexts ---------------------------------------------------
     def parse_contexts(self, profile_paths: list[str], timer: _PhaseTimer,
                        unified: ContextTree | None = None, executor=None):
@@ -308,19 +317,10 @@ class StreamingAggregator:
             raise ValueError(f"unknown plane_transport "
                              f"{self.cfg.plane_transport!r}; expected 'shm' "
                              f"or 'pickle'")
-        if self.cfg.compute not in ("cpu", "device"):
-            raise ValueError(f"unknown compute {self.cfg.compute!r}; "
-                             f"expected 'cpu' or 'device'")
         if self.cfg.stats_merge not in ("auto", "inline", "workers"):
             raise ValueError(f"unknown stats_merge {self.cfg.stats_merge!r}; "
                              f"expected 'auto', 'inline' or 'workers'")
-        if self.cfg.compute == "device" and self.cfg.pipeline == "legacy":
-            raise ValueError("compute='device' requires pipeline='fused'; "
-                             "the legacy three-pass chain has no device path")
-        if self.cfg.compute == "device" and self.cfg.executor == "ranks":
-            raise ValueError("compute='device' is not supported under the "
-                             "ranks driver; use serial/threads/processes")
-        with self._executor() as ex:
+        with get_executor(self.cfg.executor, self.cfg.workers) as ex:
             if ex.driver == "ranks":
                 # whole-run driver backend (paper §4.4): n_workers ranks,
                 # n_threads threads per rank; imported lazily — the rank
@@ -512,12 +512,16 @@ class StreamingAggregator:
         if cfg.write_cms:
             cms_path = os.path.join(self.out_dir, "db.cms")
             t2 = time.perf_counter()
+            cms_counts: dict[str, float] = {}
             cms_bytes = cms_mod.build_cms(
                 pms.path, cms_path, n_workers=cfg.cms_workers,
                 strategy=cfg.cms_strategy, balance=cfg.cms_balance,
                 group_target_bytes=cfg.group_target_bytes,
-                executor=cfg.executor, compute=cfg.effective_compute())
+                executor=cfg.executor, timings=cms_counts,
+                compute=cfg.compute)
             timer.add("cms", time.perf_counter() - t2)
+            for k, v in cms_counts.items():
+                timer.add(k, v)
         timer.add("completion", time.perf_counter() - t0)
         timer.add("total", time.perf_counter() - t_start)
 
@@ -629,14 +633,14 @@ def phase2_stream_inprocess(profile_paths: list[str], remap_of, route_of,
     runs on worker threads as soon as a profile's trace is remapped.
     Returns the sink (``max_pending`` observability).
 
-    ``device=None`` with ``cfg.effective_compute() == "device"`` builds a
+    ``device=None`` with ``cfg.compute == "device"`` builds a
     :class:`repro.kernels.batch.DeviceAggregator` for this run; worker
     threads then coalesce their propagation work into shared launches (and
     the jax dispatch releases the GIL — the ``threads`` backend's hot-loop
     rescue, ROADMAP item 3).
     """
     n = len(profile_paths)
-    if device is None and cfg.effective_compute() == "device":
+    if device is None and cfg.compute == "device":
         from repro.kernels.batch import DeviceAggregator
         device = DeviceAggregator(end_arr)
     sink = OrderedSink(lambda i, item: consume(i, *item),
@@ -666,6 +670,9 @@ def phase2_stream_inprocess(profile_paths: list[str], remap_of, route_of,
     timer.add("sink_peak", float(sink.max_pending))
     if device is not None:
         timer.add("device_launches", float(device.launches))
+        timer.add("device_inclusive_launches",
+                  float(device.inclusive_launches))
+        timer.add("device_combine_launches", float(device.combine_launches))
         timer.add("device_requests", float(device.requests))
     return sink
 
@@ -727,7 +734,7 @@ def phase2_stream_sharded(profile_paths: list[str], remaps_final,
 
     sink = OrderedSink(_consume, window=window)
     initargs = (end_arr, parent_pre, cfg.keep_exclusive, cfg.write_traces,
-                cfg.pipeline, cfg.shm_slab_bytes, cfg.effective_compute())
+                cfg.pipeline, cfg.shm_slab_bytes)
 
     def task_source():
         # pulled lazily by map_throttled, one task per credit: with the
@@ -788,22 +795,14 @@ _STAT_FIELDS = ("keys", "sum", "cnt", "vmin", "vmax", "sumsq")
 
 
 def _phase2_init(end: np.ndarray, parent: np.ndarray, keep_exclusive: bool,
-                 write_traces: bool, pipeline: str, slab_bytes: int,
-                 compute: str = "cpu") -> None:
+                 write_traces: bool, pipeline: str, slab_bytes: int) -> None:
     """Pool initializer: ship the (large) preorder-interval arrays once per
-    worker instead of once per profile task.  With ``compute="device"``
-    each worker builds its own :class:`DeviceAggregator` — workers are
-    single-threaded, so batches degenerate to size 1, but batch-composition
-    independence makes the arithmetic (and the bytes) identical."""
+    worker instead of once per profile task."""
     global _PHASE2_STATE
-    device = None
-    if compute == "device":
-        from repro.kernels.batch import DeviceAggregator
-        device = DeviceAggregator(np.asarray(end, dtype=np.int64))
     _PHASE2_STATE = (np.asarray(end, dtype=np.int64),
                      np.asarray(parent, dtype=np.int64),
                      bool(keep_exclusive), bool(write_traces), pipeline,
-                     int(slab_bytes), device)
+                     int(slab_bytes))
 
 
 def _plane_section_lengths(nb_payload: int, n_trace: int,
@@ -822,22 +821,14 @@ def _phase2_profile_worker(task) -> tuple:
     (``("shm", ...)`` descriptor), else pickled inline (``("raw", ...)``).
     """
     path, remap_final, routes_final, slab_name = task
-    # Chaos hook: the worker-death liveness tests SIGKILL a worker
-    # mid-batch via the environment, which — unlike a monkeypatched worker
-    # body — reaches spawn-context children (the default pool context for
-    # compute="device").
-    _marker = os.environ.get("REPRO_CHAOS_KILL_MARKER")
-    if _marker and _marker in str(path):
-        import signal
-        os.kill(os.getpid(), signal.SIGKILL)
     assert _PHASE2_STATE is not None, "phase-2 worker used without initializer"
     (end, parent, keep_exclusive, write_traces, pipeline,
-     slab_bytes, device) = _PHASE2_STATE
+     slab_bytes) = _PHASE2_STATE
     prof = MeasurementProfile.load(path)
     sm, acc, tr = transform_profile(prof, remap_final, routes_final, parent,
                                     end, pipeline=pipeline,
                                     keep_exclusive=keep_exclusive,
-                                    want_trace=write_traces, device=device)
+                                    want_trace=write_traces)
     if tr is not None:
         ttime, tctx = tr.time, tr.ctx
     else:

@@ -107,14 +107,15 @@ def stripe_from_buffer(buf, off: int, mid: int
 # pass 1: size census over the PMS planes
 # ---------------------------------------------------------------------------
 
-def census(pms: PMSReader, n_ctx: int, compute: str = "cpu"
-           ) -> tuple[np.ndarray, np.ndarray]:
+def census(pms: PMSReader, n_ctx: int, compute: str = "cpu",
+           timings: dict | None = None) -> tuple[np.ndarray, np.ndarray]:
     """Per-context (x_c, m_c): total values and distinct non-empty metrics.
 
     ``compute="device"`` routes the x_c histogram through the Pallas
     ``scatter_add`` kernel on real accelerators (counts are integers under
     the 2^24 f32-exactness guard, so the result is byte-identical); the
-    helper returns None on plain hosts and the numpy path runs instead.
+    helper returns None on the CPU backend and the numpy path runs instead.
+    ``timings`` receives ``device_census_launches``.
     """
     key_chunks: list[np.ndarray] = []
     uniq = np.empty(0, dtype=np.uint64)
@@ -137,6 +138,8 @@ def census(pms: PMSReader, n_ctx: int, compute: str = "cpu"
     if compute == "device":
         from repro.kernels import batch
         x_c = batch.device_census_counts(rows_all, n_ctx)
+        if timings is not None:
+            timings["device_census_launches"] = float(x_c is not None)
     if x_c is None:
         x_c = np.bincount(rows_all, minlength=n_ctx).astype(np.int64)
     m_c = np.bincount((uniq >> np.uint64(16)).astype(np.int64), minlength=n_ctx)
@@ -297,19 +300,23 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
 
     ``compute="device"`` runs the census histogram and the §4.3.2 offset
     scan through the Pallas kernels; both are exact integer ops, so the
-    file bytes never depend on the backend.
+    file bytes never depend on the backend.  ``timings``, when given,
+    receives the number of device launches of each
+    (``device_census_launches``, ``device_scan_launches``).
     """
     pms = PMSReader(pms_path)
     n_ctx = len(pms.tree.parent) if pms.tree is not None else (
         int(max((int(pms.plane(p).ctx.max()) for p in range(pms.n_profiles)
                  if pms.plane(p).n_contexts), default=-1)) + 1)
-    x_c, m_c = census(pms, n_ctx, compute=compute)
+    x_c, m_c = census(pms, n_ctx, compute=compute, timings=timings)
     sizes = np.where(x_c > 0, 60 + 10 * m_c + 12 * x_c, 0).astype(np.int64)
     offsets = np.zeros(n_ctx + 1, dtype=np.uint64)
     scanned = None
     if compute == "device":
         from repro.kernels import batch
         scanned = batch.device_offsets(sizes)  # int32 exclusive_scan kernel
+        if timings is not None:
+            timings["device_scan_launches"] = float(scanned is not None)
     if scanned is not None:
         offsets[:] = scanned
     else:
@@ -321,13 +328,7 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
     gather = _gather_group_vectorized if strategy == "vectorized" else _gather_group_heap
 
     from repro.runtime import get_executor
-    ex_kwargs = {}
-    if (compute == "device" and (executor or "threads") == "processes"
-            and not os.environ.get("REPRO_MP_CONTEXT")):
-        # deciding compute="device" initialized XLA in this process; forking
-        # a threaded XLA parent can deadlock the children
-        ex_kwargs["mp_context"] = "spawn"
-    ex = get_executor(executor or "threads", n_workers, **ex_kwargs)
+    ex = get_executor(executor or "threads", n_workers)
 
     f = open(str(out_path), "w+b")
     fd = f.fileno()

@@ -149,20 +149,38 @@ def _combine_sorted_device(keys: np.ndarray, vals: np.ndarray, device):
     return ukeys[keep], sums[keep]
 
 
-def _inclusive_device(ectx, evals, col, m, prof_mids, end, device):
+def _subtree_support(ectx, col, m, parent):
+    """The (position, column) pairs whose subtree holds a value of that
+    column — the entries and their ancestors, sorted row-major: the only
+    pairs with a non-zero inclusive sum."""
+    key = np.unique(ectx * m + col)
+    levels = [key]
+    while key.size:
+        up = parent[key // m]
+        key = np.unique((up * m + key % m)[up >= 0])
+        levels.append(key)
+    support = np.unique(np.concatenate(levels))
+    return support // m, support % m
+
+
+def _inclusive_device(ectx, evals, col, m, prof_mids, parent, end, device):
     """Inclusive propagation on device: densify the combined exclusive
     stream to (n, m) f32 and batch it through the blockscan launch — the
     cumsum formulation of :func:`_inclusive_dense`, with f32 accumulation
     (byte-identical for "exact"-class planes, documented f32 rounding
-    otherwise)."""
+    otherwise).  Only pairs whose subtree holds a value are read back:
+    elsewhere the exact sum is 0, while the difference of two f32 prefix
+    sums, accumulated in different orders, can be a stray last-bit one."""
     n = end.size
     dense = np.zeros((n, m), dtype=np.float32)
     dense[ectx, col] = evals  # combined keys are unique: plain assignment
     incl = device.inclusive(dense)
-    ir, ic = np.nonzero(incl)
-    ikeys = ir.astype(np.int64) * (1 << _KEY_SHIFT) \
-        + (prof_mids[ic] | INCLUSIVE_BIT)
-    return ikeys, incl[ir, ic].astype(np.float64)
+    ir, ic = _subtree_support(ectx, col, m, parent)
+    ivals = incl[ir, ic]
+    nz = ivals != 0.0
+    ir, ic = ir[nz], ic[nz]
+    ikeys = ir * (1 << _KEY_SHIFT) + (prof_mids[ic] | INCLUSIVE_BIT)
+    return ikeys, ivals[nz].astype(np.float64)
 
 
 def _inclusive_dense(ectx, evals, col, m, prof_mids, end):
@@ -282,16 +300,17 @@ def fused_transform(
     col = np.searchsorted(prof_mids, emid)
 
     n = end.size
+    parent = np.asarray(parent, np.int64)
     if device is not None:
-        ikeys, ivals = _inclusive_device(ectx, evals, col, m, prof_mids, end,
-                                         device)
+        ikeys, ivals = _inclusive_device(ectx, evals, col, m, prof_mids,
+                                         parent, end, device)
         return _assemble_final(ekeys, evals, ikeys, ivals, keep_exclusive)
     u = np.count_nonzero(np.diff(ectx, prepend=-1))  # distinct touched ctxs
     if n * m <= DENSE_SMALL or u >= max(1, int(n * DENSE_FRACTION)):
         ikeys, ivals = _inclusive_dense(ectx, evals, col, m, prof_mids, end)
     else:
         ikeys, ivals = _inclusive_sparse(ectx, evals, col, m, prof_mids,
-                                         np.asarray(parent, np.int64), end)
+                                         parent, end)
     return _assemble_final(ekeys, evals, ikeys, ivals, keep_exclusive)
 
 

@@ -65,29 +65,10 @@ DEVICE_COMBINE_MIN = 4096
 _EXACT_LIMIT = 2.0 ** 24
 
 
-def device_available() -> bool:
-    """jax importable at all (the container bakes it in; stubbed envs may
-    not)."""
-    try:
-        import jax  # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
 def has_accelerator() -> bool:
-    """A real accelerator backend (TPU/GPU) — not the CPU client."""
-    if not device_available():
-        return False
+    """JAX's default backend is an accelerator (TPU/GPU), not the CPU."""
     import jax
-    return jax.default_backend() not in ("cpu",)
-
-
-def device_ok(allow_interpret: bool = False) -> bool:
-    """Can ``compute="device"`` run here?  Yes with a real accelerator;
-    on a CPU-only host only when the caller opted into the interpret-mode
-    proxy (tests and benches do; production configs fall back to cpu)."""
-    return has_accelerator() or (allow_interpret and device_available())
+    return jax.default_backend() != "cpu"
 
 
 def classify_plane(vals) -> str:
@@ -131,11 +112,8 @@ class DeviceAggregator:
     funnel that coalesces concurrent threads' inclusive-propagation work
     into single launches.
 
-    One instance serves one phase-2 run: shared by all worker threads on
-    the in-process path, one per worker process on the sharded path (where
-    each worker is single-threaded, so batches degenerate to size 1 but
-    keep the identical arithmetic — composition independence makes that a
-    non-event for output bytes).
+    One instance serves one phase-2 run, shared by all worker threads of
+    the one process that holds the device.
     """
 
     def __init__(self, end: np.ndarray, *, offload_combine: bool | None = None,
@@ -165,8 +143,13 @@ class DeviceAggregator:
         self._pending: list[_Request] = []
         self._launching = False
         # observability (reported through AnalysisResult.timings)
-        self.launches = 0
+        self.inclusive_launches = 0
+        self.combine_launches = 0
         self.requests = 0
+
+    @property
+    def launches(self) -> int:
+        return self.inclusive_launches + self.combine_launches
 
     # -- inclusive propagation (the batched hot loop) ------------------------
 
@@ -201,7 +184,7 @@ class DeviceAggregator:
             mat = (batch[0].cols if len(batch) == 1
                    else np.concatenate([r.cols for r in batch], axis=1))
             out = self._inclusive_padded(mat)
-            self.launches += 1
+            self.inclusive_launches += 1
             o = 0
             for r, w in zip(batch, widths):
                 r.out = out[:, o:o + w]
@@ -246,7 +229,7 @@ class DeviceAggregator:
         v[:x] = vals
         out = self._ops.segstats(self._jnp.asarray(ids),
                                  self._jnp.asarray(v), sb)
-        self.launches += 1
+        self.combine_launches += 1
         return np.asarray(out[:n_seg, 0], dtype=np.float64)
 
 
@@ -259,11 +242,9 @@ def device_offsets(sizes: np.ndarray) -> np.ndarray | None:
     (the container runs without x64; f32 would corrupt offsets > 2^24).
     Integer cumsum is exact, so the result is byte-identical to
     ``np.cumsum`` and CMS output bytes never depend on the backend.
-    Returns None (caller falls back to numpy) when jax is unavailable or
-    the total would overflow int32 — decisions that depend only on the
-    sizes, so every executor path makes them identically."""
-    if not device_available():
-        return None
+    Returns None (caller falls back to numpy) when the total would
+    overflow int32 — a decision that depends only on the sizes, so every
+    executor path makes it identically."""
     sizes = np.asarray(sizes, dtype=np.int64)
     if sizes.size == 0 or int(sizes.sum()) >= np.iinfo(np.int32).max:
         return None

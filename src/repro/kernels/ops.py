@@ -2,8 +2,9 @@
 
 Wrappers own padding/alignment (block-multiple lengths, out-of-range
 sentinel ids) and backend selection: on TPU the compiled kernels run
-natively; on the CPU container they execute under ``interpret=True`` so
-every test validates the actual kernel bodies against the jnp oracles.
+natively; on the CPU backend, and only there, they execute under
+``interpret=True`` so every test validates the actual kernel bodies
+against the jnp oracles.  Any other backend compiles them or fails.
 """
 from __future__ import annotations
 
@@ -20,10 +21,18 @@ from repro.kernels import segstats as _ss
 
 LANE = 128     # minor-dim tile multiple (f32, TPU v4/v5)
 SUBLANE = 8    # second-minor tile multiple (f32)
+ID_TILE = 1024  # XLA's tile for a 1-D s32/f32 array; a 1-D block must match
+
+# kernel name -> the modes ("compiled" / "interpret") it was traced in by
+# this process: the evidence that a run on the chip interpreted nothing
+TRACED_MODES: dict[str, set[str]] = {}
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret(kernel: str) -> bool:
+    interpret = jax.default_backend() == "cpu"
+    TRACED_MODES.setdefault(kernel, set()).add(
+        "interpret" if interpret else "compiled")
+    return interpret
 
 
 def _align_up(x: int, mult: int) -> int:
@@ -62,10 +71,12 @@ def segstats(ids: jax.Array, vals: jax.Array, num_segments: int,
     min=max=0 (matching :class:`repro.core.stats.StatsAccumulator`).
     """
     block_s = _clamp_block(block_s, num_segments, LANE)
+    block_n = _clamp_block(block_n, ids.shape[0], ID_TILE)
     ids = _pad_to(ids.astype(jnp.int32), block_n, num_segments)
     vals = _pad_to(vals.astype(jnp.float32), block_n, 0)
     out = _ss.segstats_pallas(ids, vals, num_segments, block_n=block_n,
-                              block_s=block_s, interpret=_interpret())
+                              block_s=block_s,
+                              interpret=_interpret("segstats"))
     out = out[:num_segments]
     empty = out[:, 1] == 0
     out = out.at[:, 2].set(jnp.where(empty, 0.0, out[:, 2]))
@@ -79,10 +90,13 @@ def blockscan(x: jax.Array, block_n: int = _bs.DEFAULT_BLOCK_N) -> jax.Array:
     squeeze = x.ndim == 1
     if squeeze:
         x = x[:, None]
-    n = x.shape[0]
+    n, m = x.shape
     block_n = _clamp_block(block_n, n, SUBLANE)
     xp = _pad_to(x, block_n, 0)
-    out = _bs.blockscan_pallas(xp, block_n=block_n, interpret=_interpret())[:n]
+    if m > _bs.MAX_BLOCK_M:  # zero columns: the scan is column-local
+        xp = _pad_to(xp.T, _bs.MAX_BLOCK_M, 0).T
+    out = _bs.blockscan_pallas(xp, block_n=block_n,
+                               interpret=_interpret("blockscan"))[:n, :m]
     return out[:, 0] if squeeze else out
 
 
@@ -98,13 +112,15 @@ def scatter_add(ids: jax.Array, vals: jax.Array, num_segments: int,
                 block_s: int = _sc.DEFAULT_BLOCK_S) -> jax.Array:
     """out[s] += vals[ids == s]; vals (N,) or (N, M); unsorted ids allowed."""
     block_s = _clamp_block(block_s, num_segments, LANE)
+    block_n = _clamp_block(block_n, ids.shape[0], ID_TILE)
     squeeze = vals.ndim == 1
     if squeeze:
         vals = vals[:, None]
     ids = _pad_to(ids.astype(jnp.int32), block_n, num_segments)
     vals = _pad_to(vals.astype(jnp.float32), block_n, 0)
     out = _sc.scatter_add_pallas(ids, vals, num_segments, block_n=block_n,
-                                 block_s=block_s, interpret=_interpret())
+                                 block_s=block_s,
+                                 interpret=_interpret("scatter_add"))
     out = out[:num_segments]
     return out[:, 0] if squeeze else out
 
@@ -119,7 +135,8 @@ def int8_quant(x: jax.Array, block_n: int = _q8.DEFAULT_BLOCK_N):
     n = x.shape[0]
     block_n = _clamp_block(block_n, n, LANE)
     xp = _pad_to(x.astype(jnp.float32), block_n, 0)
-    q, s, e = _q8.int8_quant_pallas(xp, block_n=block_n, interpret=_interpret())
+    q, s, e = _q8.int8_quant_pallas(xp, block_n=block_n,
+                                    interpret=_interpret("int8_quant"))
     return q[:n], s, e[:n]
 
 

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_N = 512
+DEFAULT_BLOCK_N = 1024  # the (1024) tile XLA gives a 1-D s32 id array
 DEFAULT_BLOCK_S = 512
 
 
@@ -35,7 +35,9 @@ def _scatter_kernel(ids_ref, val_ref, out_ref, *, block_s: int):
     local = ids - j * block_s
     cols = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], block_s), 1)
     onehot = (local[:, None] == cols).astype(vals.dtype)   # (B, T)
-    out_ref[...] += jnp.dot(onehot.T, vals, preferred_element_type=jnp.float32)
+    # full f32 precision: integer counts stay exact (the "exact" contract)
+    out_ref[...] += jnp.dot(onehot.T, vals, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
 
 
 def scatter_add_pallas(ids: jax.Array, vals: jax.Array, num_segments: int,

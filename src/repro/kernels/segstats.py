@@ -18,9 +18,10 @@ tile (nb*ns*B*4 bytes) and does O(B*T) MXU work — for T ≤ 1k the extra
 flops are far below the 197 TF/s roof while avoiding HBM-bound
 gather/scatter, which TPUs lack.
 
-Block sizes (v5e): B=512 values x T=512 segments -> mask tile is
-512x512xf32 = 1 MiB of VMEM (~3 MiB total working set), well inside the
-16 MiB/core budget and 128-aligned on both MXU operand dims.
+Block sizes (v5e): B=1024 values x T=512 segments -> mask tile is
+1024x512xf32 = 2 MiB of VMEM, well inside the 16 MiB/core budget and
+128-aligned on both MXU operand dims.  B matches the (1024) tile XLA gives
+a 1-D s32/f32 array; Mosaic refuses a 1-D block that does not.
 """
 from __future__ import annotations
 
@@ -30,12 +31,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_N = 512   # values per block
+DEFAULT_BLOCK_N = 1024  # values per block: XLA tiles 1-D s32/f32 by 1024
 DEFAULT_BLOCK_S = 512   # segments per tile
 
 # output rows are padded to a lane-aligned 8 columns:
 # [sum, cnt, min, max, sumsq, 0, 0, 0]
 N_STATS = 8
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _segstats_kernel(ids_ref, val_ref, out_ref, *, block_s: int):
@@ -44,8 +47,11 @@ def _segstats_kernel(ids_ref, val_ref, out_ref, *, block_s: int):
 
     @pl.when(i == 0)
     def _init():
-        out = jnp.zeros_like(out_ref)
-        out_ref[...] = out.at[:, 2].set(jnp.inf).at[:, 3].set(-jnp.inf)
+        # min starts at +inf, max at -inf, the sums at 0 (Mosaic lowers no
+        # scatter, so no .at[].set)
+        col = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+        out_ref[...] = jnp.where(col == 2, jnp.inf,
+                                 jnp.where(col == 3, -jnp.inf, 0.0))
 
     ids = ids_ref[...]            # (B,) int32 (global segment ids, sorted)
     vals = val_ref[...]           # (B,) f32
@@ -53,10 +59,13 @@ def _segstats_kernel(ids_ref, val_ref, out_ref, *, block_s: int):
     local = ids - seg0
     cols = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], block_s), 1)
     mask = (local[:, None] == cols).astype(vals.dtype)     # (B, T)
-    # MXU contractions
-    s = jnp.dot(mask.T, vals, preferred_element_type=jnp.float32)
+    # MXU contractions at full f32 precision: a reduced-precision pass would
+    # round integer values above 2^8 and break the "exact" dtype contract
+    s = jnp.dot(mask.T, vals, precision=_HIGHEST,
+                preferred_element_type=jnp.float32)
     c = jnp.sum(mask, axis=0)
-    q = jnp.dot(mask.T, vals * vals, preferred_element_type=jnp.float32)
+    q = jnp.dot(mask.T, vals * vals, precision=_HIGHEST,
+                preferred_element_type=jnp.float32)
     # VPU masked min/max
     big = jnp.asarray(jnp.inf, vals.dtype)
     mn = jnp.min(jnp.where(mask > 0, vals[:, None], big), axis=0)

@@ -33,6 +33,7 @@ import sys
 
 from repro.core.aggregate import AggregationConfig, StreamingAggregator
 from repro.runtime import available_executors
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _aggregate_main(argv):
@@ -61,11 +62,13 @@ def _aggregate_main(argv):
     ap.add_argument("--no-traces", action="store_true")
     ap.add_argument("--compute", default="cpu", choices=["cpu", "device"],
                     help="phase-2 hot-loop backend: numpy, or the Pallas "
-                         "kernels (falls back to cpu without an accelerator)")
+                         "kernels on the accelerator; 'device' fails, naming "
+                         "the platform JAX found, when there is none, and "
+                         "runs only with --executor serial or threads")
     ap.add_argument("--device-interpret", action="store_true",
-                    help="let --compute device run on the interpret-mode "
-                         "kernel proxy when no accelerator is attached "
-                         "(slow; exercises the real kernel bodies)")
+                    help="let --compute device run the kernels in interpret "
+                         "mode on the CPU backend (slow; exercises the real "
+                         "kernel bodies)")
     args = ap.parse_args(argv)
 
     executor = args.executor or "threads"
@@ -88,13 +91,15 @@ def _aggregate_main(argv):
         compute=args.compute,
         device_interpret=args.device_interpret,
     )
+    if cfg.compute == "device":
+        enable_compile_cache()
     res = StreamingAggregator(args.out, cfg).run(args.profiles)
     runtime = (f"ranks={cfg.workers}x{args.threads}t"
                if executor == "ranks" else executor)
     print(json.dumps({
         "pms": res.pms_path, "cms": res.cms_path, "traces": res.trace_path,
         "executor": runtime, "workers": cfg.workers,
-        "compute": cfg.effective_compute(),
+        "compute": cfg.compute,
         "profiles": res.n_profiles, "contexts": res.n_contexts,
         "values": res.n_values, "sizes": res.sizes,
         "timings": {k: round(v, 4) if isinstance(v, float) else v

@@ -37,7 +37,6 @@ from repro.models.api import (batch_specs, batch_struct, build_model,
 from repro.sharding.specs import set_rules
 from repro.train.loop import make_train_step
 from repro.train.optimizer import AdamWConfig
-from repro.utils.jaxcompat import cost_analysis_dict
 
 # long-context decode requires sub-quadratic history handling: only the
 # SSM/hybrid archs run long_500k (DESIGN.md §Arch-applicability).
@@ -131,7 +130,7 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     mem = compiled.memory_analysis()
     rf = roofline.analyze(compiled, n_chips=n_chips,
                           model_flops=model_flops(cfg, shape))
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     hbm_per_dev = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                    + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     return {

@@ -8,26 +8,19 @@ leading ``pod`` axis (2 x 16 x 16 = 512 chips).
 from __future__ import annotations
 
 import jax
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` exists from jax 0.5; older jaxlibs get the default
-    (equivalent: every axis auto-sharded)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int = 1):
     """Small mesh over forced host devices for tests."""
     if pod > 1:
         return jax.make_mesh((pod, data, model), ("pod", "data", "model"),
-                             **_mesh_kwargs(3))
-    return jax.make_mesh((data, model), ("data", "model"), **_mesh_kwargs(2))
+                             (AxisType.Auto,) * 3)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         (AxisType.Auto,) * 2)

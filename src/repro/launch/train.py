@@ -24,7 +24,6 @@ from repro.models.api import build_model
 from repro.profiling import Profiler
 from repro.train.loop import Trainer, TrainerConfig, make_train_step
 from repro.train.optimizer import AdamWConfig
-from repro.utils.jaxcompat import cost_analysis_dict
 
 
 def main():
@@ -72,7 +71,7 @@ def main():
     if profiler is not None:
         compiled = jax.jit(make_train_step(model, AdamWConfig())).lower(
             params, opt, {"tokens": jnp.asarray(pipe.batch_at(start))}).compile()
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         profiler.attribute_compiled(compiled.as_text(),
                                     measured={"flops": ca.get("flops", 0.0)},
                                     struct_dir=os.path.join(args.profile_dir,

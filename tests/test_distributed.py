@@ -158,14 +158,14 @@ def test_dryrun_cell_mini_multipod():
     """The dry-run machinery itself on an 8-device (2,2,2) pod mesh."""
     out = run_sub("""
         import jax, jax.numpy as jnp
+        from jax.sharding import AxisType
         import repro.launch.mesh as mesh_mod
         # shrink the production mesh to the forced-device pool
         mesh_mod.make_production_mesh = lambda multi_pod=False: (
             jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                          **mesh_mod._mesh_kwargs(3))
+                          (AxisType.Auto,) * 3)
             if multi_pod else
-            jax.make_mesh((2, 4), ("data", "model"),
-                          **mesh_mod._mesh_kwargs(2)))
+            jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2))
         import repro.launch.dryrun as dr
         dr.make_production_mesh = mesh_mod.make_production_mesh
         import repro.configs.base as base

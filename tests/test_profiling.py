@@ -63,8 +63,7 @@ def test_profiler_end_to_end_through_aggregation(tmp_path):
         # attribute the compiled step's device costs (device-metric analog)
         compiled = jax.jit(make_train_step(model, AdamWConfig())).lower(
             params, opt, {"tokens": jnp.asarray(pipe.batch_at(0))}).compile()
-        from repro.utils.jaxcompat import cost_analysis_dict
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         prof.attribute_compiled(compiled.as_text(),
                                 measured={"flops": ca.get("flops", 0.0)},
                                 struct_dir=str(tmp_path / "structs"))

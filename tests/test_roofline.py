@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.analysis import hlo_cost, roofline
-from repro.utils.jaxcompat import cost_analysis_dict
 
 
 def _compile(f, *args):
@@ -26,7 +25,7 @@ def test_scan_trip_count_scaling():
     expect = 8 * 2 * 256**3
     assert expect * 0.95 < cost.flops < expect * 1.2, cost.flops
     # XLA's own count misses the loop: ours must be ~8x larger
-    xla = cost_analysis_dict(compiled)["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert cost.flops > 6 * xla
 
 
@@ -62,13 +61,12 @@ def test_no_loop_matches_xla_cost_analysis():
     b = jax.ShapeDtypeStruct((512, 512), jnp.float32)
     compiled = _compile(f, a, b)
     cost = hlo_cost.analyze_text(compiled.as_text())
-    xla = cost_analysis_dict(compiled)["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert abs(cost.flops - xla) / xla < 0.2
 
 
 def test_collective_bytes_sharded(force8):
-    from repro.launch.mesh import _mesh_kwargs
-    mesh = jax.make_mesh((8,), ("data",), **_mesh_kwargs(1))
+    mesh = jax.make_mesh((8,), ("data",), (jax.sharding.AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x, w):
