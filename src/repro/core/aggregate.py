@@ -54,6 +54,7 @@ from repro.core.pipeline import transform_plane
 from repro.core.pms import PMSWriter
 from repro.core.sparse import MeasurementProfile, Trace
 from repro.core.stats import StatsAccumulator
+from repro.core.timer import PhaseTimer
 from repro.core.traces import TraceDBWriter
 from repro.runtime import OrderedSink, get_executor
 from repro.runtime import shm as shm_mod
@@ -168,18 +169,6 @@ class AnalysisResult:
     sizes: dict[str, int] = field(default_factory=dict)
 
 
-class _PhaseTimer:
-    """Accumulates io/compute seconds across threads (Fig. 6 breakdown)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.acc: dict[str, float] = {}
-
-    def add(self, key: str, dt: float) -> None:
-        with self._lock:
-            self.acc[key] = self.acc.get(key, 0.0) + dt
-
-
 class TwoBufferWriter:
     """The two-buffer PMS output scheme of paper §4.3.1.
 
@@ -188,10 +177,9 @@ class TwoBufferWriter:
     performs the write while other threads keep appending to the twin.
     """
 
-    def __init__(self, pms: PMSWriter, threshold: int, timer: _PhaseTimer):
+    def __init__(self, pms: PMSWriter, threshold: int):
         self._pms = pms
         self._threshold = threshold
-        self._timer = timer
         self._pool: queue.Queue = queue.Queue()
         self._pool.put(bytearray())
         self._pool.put(bytearray())
@@ -219,9 +207,7 @@ class TwoBufferWriter:
             self._recycle(buf)
             return
         region = self._pms.alloc(len(buf))
-        t0 = time.perf_counter()
         self._pms.write_at(region, bytes(buf))
-        self._timer.add("io_write", time.perf_counter() - t0)
         for pid, off, nb, n_ctx, n_vals, ident in recs:
             self._pms.record_plane(pid, region + off, nb, n_ctx, n_vals, ident)
         self._recycle(buf)
@@ -297,7 +283,7 @@ class StreamingAggregator:
         self.cfg = config or AggregationConfig()
 
     # -- phase 1: contexts ---------------------------------------------------
-    def parse_contexts(self, profile_paths: list[str], timer: _PhaseTimer,
+    def parse_contexts(self, profile_paths: list[str], timer: PhaseTimer,
                        unified: ContextTree | None = None, executor=None):
         """Parallel parse + unify; returns (unified, remaps, routes, meta).
 
@@ -337,76 +323,79 @@ class StreamingAggregator:
     # -- in-process path (serial / threads) ------------------------------------
     def _run_inprocess(self, profile_paths: list[str], ex) -> AnalysisResult:
         cfg = self.cfg
-        timer = _PhaseTimer()
+        timer = PhaseTimer()
         t_start = time.perf_counter()
         n = len(profile_paths)
 
         # ---- phase 1
-        t0 = time.perf_counter()
-        unified, remaps, routes, identities, trace_lens, registries = (
-            self.parse_contexts(profile_paths, timer, executor=ex))
-        # renumber contexts to canonical preorder ids: subtree intervals
-        # become contiguous and CMS context order matches tree order
-        pos, order, end = unified.preorder()
-        final_tree = _renumber(unified, pos, order)
+        with timer.span("phase1", wall=True):
+            unified, remaps, routes, identities, trace_lens, registries = (
+                self.parse_contexts(profile_paths, timer, executor=ex))
+            # renumber contexts to canonical preorder ids: subtree intervals
+            # become contiguous and CMS context order matches tree order
+            with timer.span("phase1/renumber"):
+                pos, order, end = unified.preorder()
+                final_tree = _renumber(unified, pos, order)
         n_ctx = len(final_tree)
-        timer.add("phase1", time.perf_counter() - t0)
 
         # ---- phase 2
-        t0 = time.perf_counter()
-        pms_path = os.path.join(self.out_dir, "db.pms")
-        pms = PMSWriter(pms_path, n)
-        writer = TwoBufferWriter(pms, cfg.buffer_bytes, timer)
-        # stats fold inside the ordered sink: in profile order with a shape
-        # that is a pure function of n, and only O(log n) accumulators live
-        stats_reducer = _make_stats_reducer(cfg)
-        trace_path = None
-        trace_writer = None
-        if cfg.write_traces and trace_lens.sum() > 0:
-            trace_path = os.path.join(self.out_dir, "db.trc")
-            trace_writer = TraceDBWriter(trace_path, [int(x) for x in trace_lens])
-        nvals = np.zeros(n, dtype=np.int64)
-        parent_pre = np.asarray(final_tree.parent, dtype=np.int64)
+        with timer.span("phase2", wall=True):
+            with timer.span("phase2/write"):
+                pms = PMSWriter(os.path.join(self.out_dir, "db.pms"), n)
+                writer = TwoBufferWriter(pms, cfg.buffer_bytes)
+                trace_path = None
+                trace_writer = None
+                if cfg.write_traces and trace_lens.sum() > 0:
+                    trace_path = os.path.join(self.out_dir, "db.trc")
+                    trace_writer = TraceDBWriter(
+                        trace_path, [int(x) for x in trace_lens])
+            # stats fold inside the ordered sink: in profile order with a
+            # shape that is a pure function of n, and only O(log n)
+            # accumulators live
+            stats_reducer = _make_stats_reducer(cfg)
+            nvals = np.zeros(n, dtype=np.int64)
+            parent_pre = np.asarray(final_tree.parent, dtype=np.int64)
 
-        def consume(i: int, payload, p_ctx: int, p_vals: int, acc) -> None:
-            # in-order append: pins region allocation to profile order
-            writer.append(i, payload, p_ctx, p_vals, identities[i])
-            stats_reducer.push(acc)
-            nvals[i] = p_vals
+            def consume(i: int, payload, p_ctx: int, p_vals: int, acc) -> None:
+                # in-order append: pins region allocation to profile order
+                with timer.span("phase2/write"):
+                    writer.append(i, payload, p_ctx, p_vals, identities[i])
+                with timer.span("phase2/stats"):
+                    stats_reducer.push(acc)
+                nvals[i] = p_vals
 
-        trace_sink = None
-        if trace_writer is not None:
-            def trace_sink(i: int, tr: Trace) -> None:
-                t2 = time.perf_counter()
-                trace_writer.write_trace(i, tr)
-                timer.add("io_write", time.perf_counter() - t2)
+            trace_sink = (trace_writer.write_trace
+                          if trace_writer is not None else None)
 
-        try:
-            phase2_stream_inprocess(
-                profile_paths,
-                lambda i: pos[np.asarray(remaps[i], dtype=np.int64)],
-                lambda i: {int(pos[ph]): (pos[t_], w)
-                           for ph, (t_, w) in routes[i].items()},
-                cfg, ex, parent_pre, end, timer, consume, trace_sink)
-            writer.close()
-        except BaseException:
-            stats_reducer.close()
-            pms.abort()
+            try:
+                phase2_stream_inprocess(
+                    profile_paths,
+                    lambda i: pos[np.asarray(remaps[i], dtype=np.int64)],
+                    lambda i: {int(pos[ph]): (pos[t_], w)
+                               for ph, (t_, w) in routes[i].items()},
+                    cfg, ex, parent_pre, end, timer, consume, trace_sink)
+                with timer.span("phase2/write"):
+                    writer.close()
+            except BaseException:
+                stats_reducer.close()
+                pms.abort()
+                if trace_writer is not None:
+                    trace_writer.close()
+                raise
             if trace_writer is not None:
-                trace_writer.close()
-            raise
-        if trace_writer is not None:
-            trace_writer.close()
-        timer.add("phase2", time.perf_counter() - t0)
+                with timer.span("phase2/write"):
+                    trace_writer.close()
 
-        return self._complete(pms, final_tree, stats_reducer.result(),
-                              registries, trace_path, timer, t_start, n,
-                              n_ctx, int(nvals.sum()))
+        with timer.span("completion/stats"):  # the fold's last merges
+            root_acc = stats_reducer.result()
+        return self._complete(pms, final_tree, root_acc, registries,
+                              trace_path, timer, t_start, n, n_ctx,
+                              int(nvals.sum()))
 
     # -- sharded path (processes) ----------------------------------------------
     def _run_sharded(self, profile_paths: list[str], ex) -> AnalysisResult:
         cfg = self.cfg
-        timer = _PhaseTimer()
+        timer = PhaseTimer()
         t_start = time.perf_counter()
         n = len(profile_paths)
         shards = ex.shards(n)
@@ -454,7 +443,7 @@ class StreamingAggregator:
         t0 = time.perf_counter()
         pms_path = os.path.join(self.out_dir, "db.pms")
         pms = PMSWriter(pms_path, n)
-        writer = TwoBufferWriter(pms, cfg.buffer_bytes, timer)
+        writer = TwoBufferWriter(pms, cfg.buffer_bytes)
         trace_path = None
         trace_writer = None
         if cfg.write_traces and trace_lens.sum() > 0:
@@ -469,12 +458,8 @@ class StreamingAggregator:
             stats_reducer.push(acc)
             nvals[i] = p_vals
 
-        trace_sink = None
-        if trace_writer is not None:
-            def trace_sink(i: int, tr: Trace) -> None:
-                t2 = time.perf_counter()
-                trace_writer.write_trace(i, tr)
-                timer.add("io_write", time.perf_counter() - t2)
+        trace_sink = (trace_writer.write_trace if trace_writer is not None
+                      else None)
 
         try:
             phase2_stream_sharded(profile_paths, remaps_final, routes_final,
@@ -499,30 +484,28 @@ class StreamingAggregator:
     def _complete(self, pms, final_tree, root_acc, registries,
                   trace_path, timer, t_start, n, n_ctx, n_values) -> AnalysisResult:
         cfg = self.cfg
-        t0 = time.perf_counter()
-        if root_acc is None:
-            root_acc = StatsAccumulator()
-        stats = root_acc.finalize()
-        registry_json = next((r for r in registries if r), [])
-        pms_bytes = pms.finalize(tree=final_tree, registry_json=registry_json,
-                                 stats={k: np.asarray(v, np.float64)
-                                        for k, v in stats.items()})
-        cms_path = None
-        cms_bytes = 0
-        if cfg.write_cms:
-            cms_path = os.path.join(self.out_dir, "db.cms")
-            t2 = time.perf_counter()
-            cms_counts: dict[str, float] = {}
-            cms_bytes = cms_mod.build_cms(
-                pms.path, cms_path, n_workers=cfg.cms_workers,
-                strategy=cfg.cms_strategy, balance=cfg.cms_balance,
-                group_target_bytes=cfg.group_target_bytes,
-                executor=cfg.executor, timings=cms_counts,
-                compute=cfg.compute)
-            timer.add("cms", time.perf_counter() - t2)
-            for k, v in cms_counts.items():
-                timer.add(k, v)
-        timer.add("completion", time.perf_counter() - t0)
+        with timer.span("completion", wall=True):
+            with timer.span("completion/stats"):
+                if root_acc is None:
+                    root_acc = StatsAccumulator()
+                stats = root_acc.finalize()
+            with timer.span("completion/pms"):
+                registry_json = next((r for r in registries if r), [])
+                pms_bytes = pms.finalize(
+                    tree=final_tree, registry_json=registry_json,
+                    stats={k: np.asarray(v, np.float64)
+                           for k, v in stats.items()})
+            cms_path = None
+            cms_bytes = 0
+            if cfg.write_cms:
+                cms_path = os.path.join(self.out_dir, "db.cms")
+                with timer.span("cms", wall=True):
+                    cms_bytes = cms_mod.build_cms(
+                        pms.path, cms_path, n_workers=cfg.cms_workers,
+                        strategy=cfg.cms_strategy, balance=cfg.cms_balance,
+                        group_target_bytes=cfg.group_target_bytes,
+                        executor=cfg.executor, timer=timer,
+                        compute=cfg.compute)
         timer.add("total", time.perf_counter() - t_start)
 
         sizes = {"pms": pms_bytes, "cms": cms_bytes}
@@ -540,7 +523,7 @@ class StreamingAggregator:
 # ingest appends)
 # ---------------------------------------------------------------------------
 
-def phase1_unify_inprocess(profile_paths: list[str], timer: _PhaseTimer,
+def phase1_unify_inprocess(profile_paths: list[str], timer: PhaseTimer,
                            unified: ContextTree | None = None, executor=None):
     """Parallel parse + unify into ``unified`` (grown in place when given —
     the live-ingest append path; a one-shot run starts from an empty tree).
@@ -572,19 +555,22 @@ def phase1_unify_inprocess(profile_paths: list[str], timer: _PhaseTimer,
     registry_jsons: list[list] = [[] for _ in range(n)]
 
     def body(i: int):
-        t0 = time.perf_counter()
-        prof = MeasurementProfile.load(profile_paths[i])
-        timer.add("io_read", time.perf_counter() - t0)
-        t1 = time.perf_counter()
-        own = _load_structures(prof, structures, struct_lock)
-        with uniq_lock:  # uniquing (U) — see module docstring on locking
-            remap, rts = expand_profile_tree(unified, prof.tree, own)
+        with timer.span("phase1/load"):
+            prof = MeasurementProfile.load(profile_paths[i])
+        with timer.span("phase1/structures"):
+            own = _load_structures(prof, structures, struct_lock)
+        with timer.span("phase1/unify_wait"):
+            uniq_lock.acquire()
+        try:  # uniquing (U) — see module docstring on locking
+            with timer.span("phase1/unify"):
+                remap, rts = expand_profile_tree(unified, prof.tree, own)
+        finally:
+            uniq_lock.release()
         remaps[i] = remap
         routes[i] = rts
         identities[i] = prof.identity
         trace_lens[i] = prof.trace.time.size
         registry_jsons[i] = prof.environment.get("registry", [])
-        timer.add("compute", time.perf_counter() - t1)
 
     ex.parallel_for(n, body)
     return unified, remaps, routes, identities, trace_lens, registry_jsons
@@ -617,7 +603,7 @@ def transform_profile(prof: MeasurementProfile, remap_final, routes_final,
 
 def phase2_stream_inprocess(profile_paths: list[str], remap_of, route_of,
                             cfg: AggregationConfig, ex, parent_pre: np.ndarray,
-                            end_arr: np.ndarray, timer: _PhaseTimer, consume,
+                            end_arr: np.ndarray, timer: PhaseTimer, consume,
                             trace_sink=None, device=None):
     """Stream phase 2 through an in-process executor with pluggable output
     hooks — the engine behind :meth:`StreamingAggregator._run_inprocess`
@@ -630,7 +616,8 @@ def phase2_stream_inprocess(profile_paths: list[str], remap_of, route_of,
     :class:`OrderedSink` — the determinism pin for region allocation and
     the stats carry chain; a bounded window blocks producers of far-ahead
     profiles instead of stacking encoded planes.  ``trace_sink(i, trace)``
-    runs on worker threads as soon as a profile's trace is remapped.
+    runs on worker threads as soon as a profile's trace is remapped, timed
+    as ``phase2/write``.
     Returns the sink (``max_pending`` observability).
 
     ``device=None`` with ``cfg.compute == "device"`` builds a
@@ -641,26 +628,34 @@ def phase2_stream_inprocess(profile_paths: list[str], remap_of, route_of,
     """
     n = len(profile_paths)
     if device is None and cfg.compute == "device":
-        from repro.kernels.batch import DeviceAggregator
-        device = DeviceAggregator(end_arr)
+        with timer.span("phase2/device"):
+            from repro.kernels.batch import DeviceAggregator
+            device = DeviceAggregator(end_arr, timer=timer)
     sink = OrderedSink(lambda i, item: consume(i, *item),
                        window=cfg.effective_sink_window)
 
     def body(i: int):
         try:
-            t0 = time.perf_counter()
-            prof = MeasurementProfile.load(profile_paths[i])
-            timer.add("io_read", time.perf_counter() - t0)
-            t1 = time.perf_counter()
-            sm, acc, tr = transform_profile(
-                prof, remap_of(i), route_of(i), parent_pre, end_arr,
-                pipeline=cfg.pipeline, keep_exclusive=cfg.keep_exclusive,
-                want_trace=trace_sink is not None, device=device)
-            payload = sm.encode()
-            timer.add("compute", time.perf_counter() - t1)
-            sink.put(i, (payload, sm.n_contexts, sm.n_values, acc))
+            with timer.span("phase2/load"):
+                prof = MeasurementProfile.load(profile_paths[i])
+            # each step drops what it last needs, so that freeing the
+            # profile's arrays is timed with the step that ends their use
+            with timer.span("phase2/transform"):
+                sm, acc, tr = transform_profile(
+                    prof, remap_of(i), route_of(i), parent_pre, end_arr,
+                    pipeline=cfg.pipeline, keep_exclusive=cfg.keep_exclusive,
+                    want_trace=trace_sink is not None, device=device)
+                del prof
+            with timer.span("phase2/encode"):
+                item = (sm.encode(), sm.n_contexts, sm.n_values, acc)
+                del sm, acc
+            with timer.span("phase2/sink_wait"):
+                sink.put(i, item)
+                del item
             if tr is not None:
-                trace_sink(i, tr)
+                with timer.span("phase2/write"):
+                    trace_sink(i, tr)
+                    del tr   # may hold the profile's mapped file
         except BaseException as e:
             sink.fail(e)  # wake producers blocked on the bounded window
             raise
@@ -680,7 +675,7 @@ def phase2_stream_inprocess(profile_paths: list[str], remap_of, route_of,
 def phase2_stream_sharded(profile_paths: list[str], remaps_final,
                           routes_final, cfg: AggregationConfig, ex,
                           parent_pre: np.ndarray, end_arr: np.ndarray,
-                          timer: _PhaseTimer, consume, trace_sink=None):
+                          timer: PhaseTimer, consume, trace_sink=None):
     """Phase-2 streaming over a ``processes`` executor with pluggable
     output hooks: propagate/encode runs in pool workers (shm slab arena or
     pickle transport), then ``consume(i, payload, n_ctx, n_vals, acc)``

@@ -27,6 +27,7 @@ import numpy as np
 from repro.utils import binio
 from repro.core import loadbalance
 from repro.core.pms import PMSReader
+from repro.core.timer import PhaseTimer
 
 CMS_MAGIC = b"RCMS"
 _HEADER = 24
@@ -108,15 +109,36 @@ def stripe_from_buffer(buf, off: int, mid: int
 # ---------------------------------------------------------------------------
 
 def census(pms: PMSReader, n_ctx: int, compute: str = "cpu",
-           timings: dict | None = None) -> tuple[np.ndarray, np.ndarray]:
+           timer: PhaseTimer | None = None) -> tuple[np.ndarray, np.ndarray]:
     """Per-context (x_c, m_c): total values and distinct non-empty metrics.
 
     ``compute="device"`` routes the x_c histogram through the Pallas
     ``scatter_add`` kernel on real accelerators (counts are integers under
     the 2^24 f32-exactness guard, so the result is byte-identical); the
     helper returns None on the CPU backend and the numpy path runs instead.
-    ``timings`` receives ``device_census_launches``.
+    ``timer`` receives the ``cms/census`` (host) and ``cms/census_device``
+    spans and ``device_census_launches``.
     """
+    timer = timer if timer is not None else PhaseTimer()
+    with timer.span("cms/census"):
+        rows_all, uniq = _census_keys(pms)
+    x_c = None
+    if compute == "device":
+        from repro.kernels import batch
+        with timer.span("cms/census_device"):
+            x_c = batch.device_census_counts(rows_all, n_ctx, timer)
+        timer.add("device_census_launches", float(x_c is not None))
+    with timer.span("cms/census"):
+        if x_c is None:
+            x_c = np.bincount(rows_all, minlength=n_ctx).astype(np.int64)
+        m_c = np.bincount((uniq >> np.uint64(16)).astype(np.int64),
+                          minlength=n_ctx)
+    return x_c, m_c.astype(np.int64)
+
+
+def _census_keys(pms: PMSReader) -> tuple[np.ndarray, np.ndarray]:
+    """Every profile's context rows, concatenated, and the distinct
+    ``ctx << 16 | mid`` keys."""
     key_chunks: list[np.ndarray] = []
     uniq = np.empty(0, dtype=np.uint64)
     row_chunks: list[np.ndarray] = []
@@ -134,16 +156,7 @@ def census(pms: PMSReader, n_ctx: int, compute: str = "cpu",
         uniq = np.unique(np.concatenate([uniq] + key_chunks))
     rows_all = (np.concatenate(row_chunks) if row_chunks
                 else np.empty(0, np.int64))
-    x_c = None
-    if compute == "device":
-        from repro.kernels import batch
-        x_c = batch.device_census_counts(rows_all, n_ctx)
-        if timings is not None:
-            timings["device_census_launches"] = float(x_c is not None)
-    if x_c is None:
-        x_c = np.bincount(rows_all, minlength=n_ctx).astype(np.int64)
-    m_c = np.bincount((uniq >> np.uint64(16)).astype(np.int64), minlength=n_ctx)
-    return x_c, m_c.astype(np.int64)
+    return rows_all, uniq
 
 
 # ---------------------------------------------------------------------------
@@ -286,7 +299,7 @@ def _shard_groups(groups, sizes: np.ndarray, n_workers: int):
 
 def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vectorized",
               balance: str = "dynamic", group_target_bytes: int = 1 << 20,
-              executor: str | None = None, timings: dict | None = None,
+              executor: str | None = None, timer: PhaseTimer | None = None,
               compute: str = "cpu") -> int:
     """Generate the CMS file from a completed PMS file (paper §4.3.2).
 
@@ -300,42 +313,46 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
 
     ``compute="device"`` runs the census histogram and the §4.3.2 offset
     scan through the Pallas kernels; both are exact integer ops, so the
-    file bytes never depend on the backend.  ``timings``, when given,
-    receives the number of device launches of each
-    (``device_census_launches``, ``device_scan_launches``).
+    file bytes never depend on the backend.  ``timer``, when given,
+    receives the ``cms/*`` spans (census, census_device, scan, gather,
+    write), the device transfer bytes and the number of device launches of
+    each (``device_census_launches``, ``device_scan_launches``).
     """
-    pms = PMSReader(pms_path)
-    n_ctx = len(pms.tree.parent) if pms.tree is not None else (
-        int(max((int(pms.plane(p).ctx.max()) for p in range(pms.n_profiles)
-                 if pms.plane(p).n_contexts), default=-1)) + 1)
-    x_c, m_c = census(pms, n_ctx, compute=compute, timings=timings)
-    sizes = np.where(x_c > 0, 60 + 10 * m_c + 12 * x_c, 0).astype(np.int64)
-    offsets = np.zeros(n_ctx + 1, dtype=np.uint64)
-    scanned = None
-    if compute == "device":
-        from repro.kernels import batch
-        scanned = batch.device_offsets(sizes)  # int32 exclusive_scan kernel
-        if timings is not None:
-            timings["device_scan_launches"] = float(scanned is not None)
-    if scanned is not None:
-        offsets[:] = scanned
-    else:
-        np.cumsum(sizes, out=offsets[1:])  # exclusive scan (paper §4.3.2)
-    data_start = _HEADER + 8 * (n_ctx + 1)
-    offsets += np.uint64(data_start)
-
-    groups = loadbalance.make_groups(sizes, group_target_bytes)
+    timer = timer if timer is not None else PhaseTimer()
+    with timer.span("cms/census"):
+        pms = PMSReader(pms_path)
+        n_ctx = len(pms.tree.parent) if pms.tree is not None else (
+            int(max((int(pms.plane(p).ctx.max()) for p in range(pms.n_profiles)
+                     if pms.plane(p).n_contexts), default=-1)) + 1)
+    x_c, m_c = census(pms, n_ctx, compute=compute, timer=timer)
+    with timer.span("cms/scan"):
+        sizes = np.where(x_c > 0, 60 + 10 * m_c + 12 * x_c, 0).astype(np.int64)
+        offsets = np.zeros(n_ctx + 1, dtype=np.uint64)
+        scanned = None
+        if compute == "device":
+            from repro.kernels import batch
+            # int32 exclusive_scan kernel
+            scanned = batch.device_offsets(sizes, timer)
+            timer.add("device_scan_launches", float(scanned is not None))
+        if scanned is not None:
+            offsets[:] = scanned
+        else:
+            np.cumsum(sizes, out=offsets[1:])  # exclusive scan (paper §4.3.2)
+        data_start = _HEADER + 8 * (n_ctx + 1)
+        offsets += np.uint64(data_start)
+        groups = loadbalance.make_groups(sizes, group_target_bytes)
     gather = _gather_group_vectorized if strategy == "vectorized" else _gather_group_heap
 
     from repro.runtime import get_executor
     ex = get_executor(executor or "threads", n_workers)
 
-    f = open(str(out_path), "w+b")
-    fd = f.fileno()
-    f.write(CMS_MAGIC + struct.pack("<I", 1))
-    f.write(struct.pack("<QQ", n_ctx, 0))
-    f.write(offsets.tobytes())
-    f.flush()  # workers use positional pwrites from here on
+    with timer.span("cms/write"):
+        f = open(str(out_path), "w+b")
+        fd = f.fileno()
+        f.write(CMS_MAGIC + struct.pack("<I", 1))
+        f.write(struct.pack("<QQ", n_ctx, 0))
+        f.write(offsets.tobytes())
+        f.flush()  # workers use positional pwrites from here on
 
     if not ex.in_process:
         tasks = [(str(pms_path), str(out_path), strategy, shard)
@@ -348,31 +365,35 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
 
         def worker(w: int):
             # every worker opens its own reader: no shared file positions
-            wpms = PMSReader(pms_path)
+            with timer.span("cms/gather"):
+                wpms = PMSReader(pms_path)
             while True:
                 g = assigner.next_group(w)
                 if g is None:
                     break
                 lo, hi = g
-                planes = gather(wpms, lo, hi)
+                with timer.span("cms/gather"):
+                    planes = gather(wpms, lo, hi)
                 if not planes:
                     continue
                 # group planes are contiguous: one buffer, one pwrite
-                buf = b"".join(planes[c] for c in sorted(planes))
-                os.pwrite(fd, buf, int(offsets[min(planes)]))
+                with timer.span("cms/write"):
+                    buf = b"".join(planes[c] for c in sorted(planes))
+                    os.pwrite(fd, buf, int(offsets[min(planes)]))
             wpms.close()
 
         with ex:
             ex.parallel_for(n_workers, worker)
 
-    meta_off = int(offsets[-1])
-    blob = binio.pack_json({"n_profiles": pms.n_profiles,
-                            "registry": pms.meta.get("registry", [])})
-    os.pwrite(fd, blob, meta_off)
-    os.pwrite(fd, struct.pack("<Q", meta_off), 16)
-    f.truncate(meta_off + len(blob))
-    f.close()
-    pms.close()
+    with timer.span("cms/write"):
+        meta_off = int(offsets[-1])
+        blob = binio.pack_json({"n_profiles": pms.n_profiles,
+                                "registry": pms.meta.get("registry", [])})
+        os.pwrite(fd, blob, meta_off)
+        os.pwrite(fd, struct.pack("<Q", meta_off), 16)
+        f.truncate(meta_off + len(blob))
+        f.close()
+        pms.close()
     return meta_off + len(blob)
 
 

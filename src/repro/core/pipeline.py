@@ -170,17 +170,21 @@ def _inclusive_device(ectx, evals, col, m, prof_mids, parent, end, device):
     (byte-identical for "exact"-class planes, documented f32 rounding
     otherwise).  Only pairs whose subtree holds a value are read back:
     elsewhere the exact sum is 0, while the difference of two f32 prefix
-    sums, accumulated in different orders, can be a stray last-bit one."""
-    n = end.size
-    dense = np.zeros((n, m), dtype=np.float32)
-    dense[ectx, col] = evals  # combined keys are unique: plain assignment
-    incl = device.inclusive(dense)
-    ir, ic = _subtree_support(ectx, col, m, parent)
-    ivals = incl[ir, ic]
-    nz = ivals != 0.0
-    ir, ic = ir[nz], ic[nz]
-    ikeys = ir * (1 << _KEY_SHIFT) + (prof_mids[ic] | INCLUSIVE_BIT)
-    return ikeys, ivals[nz].astype(np.float64)
+    sums, accumulated in different orders, can be a stray last-bit one.
+    Each step is a span of the device's run timer."""
+    timer = device.timer
+    with timer.span("phase2/densify"):
+        dense = np.zeros((end.size, m), dtype=np.float32)
+        dense[ectx, col] = evals  # combined keys are unique: plain assignment
+    with timer.span("phase2/device"):
+        incl = device.inclusive(dense)
+    with timer.span("phase2/support"):
+        ir, ic = _subtree_support(ectx, col, m, parent)
+        ivals = incl[ir, ic]
+        nz = ivals != 0.0
+        ir, ic = ir[nz], ic[nz]
+        ikeys = ir * (1 << _KEY_SHIFT) + (prof_mids[ic] | INCLUSIVE_BIT)
+        return ikeys, ivals[nz].astype(np.float64)
 
 
 def _inclusive_dense(ectx, evals, col, m, prof_mids, end):

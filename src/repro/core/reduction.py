@@ -24,12 +24,13 @@ import numpy as np
 
 from repro.core import cms as cms_mod
 from repro.core.aggregate import (AggregationConfig, AnalysisResult,
-                                  StreamingAggregator, _PhaseTimer, _renumber)
+                                  StreamingAggregator, _renumber)
 from repro.core.cct import ContextTree
 from repro.core.pipeline import transform_plane
 from repro.core.pms import PMSWriter
 from repro.core.sparse import MeasurementProfile
 from repro.core.stats import StatsAccumulator
+from repro.core.timer import PhaseTimer
 from repro.core.traces import TraceDBWriter
 # the generic reduction machinery is shared with the executor runtime
 # (re-exported here for back-compat: tests and callers import it from us)
@@ -47,7 +48,7 @@ __all__ = ["aggregate_multiprocess", "tree_reduce"]
 def _phase1_worker(args):
     rank, paths, n_threads = args
     agg = StreamingAggregator(out_dir="/tmp", config=AggregationConfig(n_threads=n_threads))
-    timer = _PhaseTimer()
+    timer = PhaseTimer()
     unified, remaps, routes, identities, trace_lens, registries = (
         agg.parse_contexts(paths, timer))
     return {

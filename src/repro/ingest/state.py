@@ -49,8 +49,7 @@ import time
 import numpy as np
 
 from repro.core import cms as cms_mod
-from repro.core.aggregate import (AggregationConfig, _merge_stats,
-                                  _PhaseTimer, _renumber,
+from repro.core.aggregate import (AggregationConfig, _merge_stats, _renumber,
                                   phase1_unify_inprocess,
                                   phase2_stream_inprocess,
                                   phase2_stream_sharded)
@@ -58,6 +57,7 @@ from repro.core.cct import ContextTree
 from repro.core.pms import PMSWriter
 from repro.core.sparse import CTX_DTYPE, IDX_DTYPE, SparseMetrics, Trace
 from repro.core.stats import StatsAccumulator
+from repro.core.timer import PhaseTimer
 from repro.core.traces import TraceDBWriter
 from repro.runtime import get_executor
 from repro.runtime.reduce import StreamingReducer
@@ -156,7 +156,7 @@ class IngestState:
         n = len(profile_paths)
         if n == 0:
             return {"appended": 0, "n_contexts": self.n_contexts}
-        timer = _PhaseTimer()
+        timer = PhaseTimer()
         t_start = time.perf_counter()
         n0_nodes = len(self.tree)
         try:
@@ -182,7 +182,7 @@ class IngestState:
                 "n_contexts": self.n_contexts,
                 "append_s": time.perf_counter() - t_start}
 
-    def _append_stream(self, profile_paths: list[str], timer: _PhaseTimer,
+    def _append_stream(self, profile_paths: list[str], timer: PhaseTimer,
                        ex) -> tuple:
         cfg = self.cfg
         n = len(profile_paths)

@@ -53,6 +53,8 @@ import threading
 
 import numpy as np
 
+from repro.core.timer import PhaseTimer
+
 LANE = 128     # minor-dim tile multiple (f32)
 SUBLANE = 8    # second-minor tile multiple (f32)
 
@@ -113,11 +115,14 @@ class DeviceAggregator:
     into single launches.
 
     One instance serves one phase-2 run, shared by all worker threads of
-    the one process that holds the device.
+    the one process that holds the device.  ``timer`` (the run's
+    :class:`repro.core.timer.PhaseTimer`) receives the ``device/*`` spans of
+    the propagation launches and the transfer and lane counters.
     """
 
     def __init__(self, end: np.ndarray, *, offload_combine: bool | None = None,
-                 combine_min: int = DEVICE_COMBINE_MIN):
+                 combine_min: int = DEVICE_COMBINE_MIN,
+                 timer: PhaseTimer | None = None):
         import jax
         import jax.numpy as jnp
 
@@ -125,11 +130,15 @@ class DeviceAggregator:
 
         self._jnp = jnp
         self._ops = ops
+        self.timer = timer if timer is not None else PhaseTimer()
         end = np.ascontiguousarray(np.asarray(end, dtype=np.int64))
         if end.size and int(end.max()) > np.iinfo(np.int32).max:
             raise ValueError("unified tree too large for int32 device ids")
         self.n = int(end.size)
-        self._end_dev = jax.device_put(jnp.asarray(end.astype(np.int32)))
+        end32 = end.astype(np.int32)
+        with self.timer.span("device/h2d"):
+            self._end_dev = jnp.asarray(end32)
+        self.timer.add("device_h2d_bytes", end32.nbytes)
         self._incl_fn = jax.jit(ops.inclusive_from_exclusive)
         self.interpret = not has_accelerator()
         # the one-hot combine is MXU free-lunch on hardware but O(n*S) host
@@ -173,7 +182,8 @@ class DeviceAggregator:
                         self._launching = False
                         break
                 self._launch(batch)
-        req.event.wait()
+        with self.timer.span("device/wait"):  # on another thread's launch
+            req.event.wait()
         if req.err is not None:
             raise req.err
         return req.out
@@ -181,8 +191,9 @@ class DeviceAggregator:
     def _launch(self, batch: list[_Request]) -> None:
         try:
             widths = [r.cols.shape[1] for r in batch]
-            mat = (batch[0].cols if len(batch) == 1
-                   else np.concatenate([r.cols for r in batch], axis=1))
+            with self.timer.span("device/pack"):
+                mat = (batch[0].cols if len(batch) == 1
+                       else np.concatenate([r.cols for r in batch], axis=1))
             out = self._inclusive_padded(mat)
             self.inclusive_launches += 1
             o = 0
@@ -197,13 +208,26 @@ class DeviceAggregator:
                 r.event.set()
 
     def _inclusive_padded(self, mat: np.ndarray) -> np.ndarray:
+        timer = self.timer
         n, m = mat.shape
         mb = _bucket(m, SUBLANE)
         if mb != m:  # zero columns: cumsum is column-local, results unchanged
-            mat = np.concatenate(
-                [mat, np.zeros((n, mb - m), dtype=np.float32)], axis=1)
-        out = self._incl_fn(self._jnp.asarray(mat), self._end_dev)
-        return np.asarray(out)[:, :m]
+            with timer.span("device/pack"):
+                mat = np.concatenate(
+                    [mat, np.zeros((n, mb - m), dtype=np.float32)], axis=1)
+        # the copy to the device returns once JAX holds the matrix; the
+        # kernel's span waits for the rest of the copy and the kernel
+        with timer.span("device/h2d"):
+            x = self._jnp.asarray(mat)
+        with timer.span("device/kernel"):
+            y = self._incl_fn(x, self._end_dev).block_until_ready()
+        with timer.span("device/d2h"):
+            out = np.asarray(y)
+        timer.add("device_h2d_bytes", mat.nbytes)
+        timer.add("device_d2h_bytes", out.nbytes)
+        timer.add("device_columns", m)
+        timer.add("device_padded_columns", mb)
+        return out[:, :m]
 
     # -- duplicate-key combine (per-profile segment sums) --------------------
 
@@ -229,39 +253,48 @@ class DeviceAggregator:
         v[:x] = vals
         out = self._ops.segstats(self._jnp.asarray(ids),
                                  self._jnp.asarray(v), sb)
+        sums = np.asarray(out[:n_seg, 0])
         self.combine_launches += 1
-        return np.asarray(out[:n_seg, 0], dtype=np.float64)
+        self.timer.add("device_h2d_bytes", ids.nbytes + v.nbytes)
+        self.timer.add("device_d2h_bytes", sums.nbytes)
+        return sums.astype(np.float64)
 
 
 # ---------------------------------------------------------------------------
 # CMS helpers (module-level: no per-run state needed)
 # ---------------------------------------------------------------------------
 
-def device_offsets(sizes: np.ndarray) -> np.ndarray | None:
+def device_offsets(sizes: np.ndarray, timer: PhaseTimer | None = None
+                   ) -> np.ndarray | None:
     """CMS stripe offsets by device exclusive scan (paper §4.3.2), int32
     (the container runs without x64; f32 would corrupt offsets > 2^24).
     Integer cumsum is exact, so the result is byte-identical to
     ``np.cumsum`` and CMS output bytes never depend on the backend.
     Returns None (caller falls back to numpy) when the total would
     overflow int32 — a decision that depends only on the sizes, so every
-    executor path makes it identically."""
+    executor path makes it identically.  ``timer`` counts the bytes each
+    way."""
     sizes = np.asarray(sizes, dtype=np.int64)
     if sizes.size == 0 or int(sizes.sum()) >= np.iinfo(np.int32).max:
         return None
     import jax.numpy as jnp
 
     from repro.kernels import ops
-    out = ops.exclusive_scan(jnp.asarray(sizes.astype(np.int32)))
-    return np.asarray(out, dtype=np.int64)
+    sizes32 = sizes.astype(np.int32)
+    out = np.asarray(ops.exclusive_scan(jnp.asarray(sizes32)))
+    _count_transfer(timer, sizes32, out)
+    return out.astype(np.int64)
 
 
-def device_census_counts(rows_all: np.ndarray, n_ctx: int) -> np.ndarray | None:
+def device_census_counts(rows_all: np.ndarray, n_ctx: int,
+                         timer: PhaseTimer | None = None) -> np.ndarray | None:
     """Per-context value counts via the one-hot ``histogram`` kernel — one
     launch over every profile's concatenated rows (unsorted ids are fine
     for scatter_add).  Real accelerators only: the O(values x contexts)
     mask work is MXU throwaway on TPU but a dealbreaker on the interpret
     proxy.  Counts are integers < 2^24 (guarded), so f32 accumulation is
-    exact and the result matches ``np.add.at`` byte-for-byte."""
+    exact and the result matches ``np.add.at`` byte-for-byte.  ``timer``
+    counts the bytes each way."""
     if not has_accelerator() or n_ctx == 0:
         return None
     rows_all = np.asarray(rows_all)
@@ -270,5 +303,14 @@ def device_census_counts(rows_all: np.ndarray, n_ctx: int) -> np.ndarray | None:
     import jax.numpy as jnp
 
     from repro.kernels import ops
-    counts = ops.histogram(jnp.asarray(rows_all.astype(np.int32)), int(n_ctx))
-    return np.asarray(counts, dtype=np.int64)
+    rows32 = rows_all.astype(np.int32)
+    counts = np.asarray(ops.histogram(jnp.asarray(rows32), int(n_ctx)))
+    _count_transfer(timer, rows32, counts)
+    return counts.astype(np.int64)
+
+
+def _count_transfer(timer: PhaseTimer | None, sent: np.ndarray,
+                    received: np.ndarray) -> None:
+    if timer is not None:
+        timer.add("device_h2d_bytes", sent.nbytes)
+        timer.add("device_d2h_bytes", received.nbytes)
