@@ -163,24 +163,26 @@ def _subtree_support(ectx, col, m, parent):
     return support // m, support % m
 
 
-def _inclusive_device(ectx, evals, col, m, prof_mids, parent, end, device):
-    """Inclusive propagation on device: densify the combined exclusive
-    stream to (n, m) f32 and batch it through the blockscan launch — the
-    cumsum formulation of :func:`_inclusive_dense`, with f32 accumulation
-    (byte-identical for "exact"-class planes, documented f32 rounding
-    otherwise).  Only pairs whose subtree holds a value are read back:
-    elsewhere the exact sum is 0, while the difference of two f32 prefix
-    sums, accumulated in different orders, can be a stray last-bit one.
-    Each step is a span of the device's run timer."""
+def _inclusive_device(ectx, evals, col, m, prof_mids, parent, device):
+    """Inclusive propagation on device: the combined exclusive stream goes
+    to the blockscan launch as (row, column, f32 value) triplets, the
+    device builds the (n, m) matrix and scans it — the cumsum formulation
+    of :func:`_inclusive_dense`, with f32 accumulation (byte-identical for
+    "exact"-class planes, documented f32 rounding otherwise) — and only
+    the pairs whose subtree holds a value come back: elsewhere the exact
+    sum is 0, while the difference of two f32 prefix sums, accumulated in
+    different orders, can be a stray last-bit one.  Each step is a span of
+    the device's run timer."""
     timer = device.timer
-    with timer.span("phase2/densify"):
-        dense = np.zeros((end.size, m), dtype=np.float32)
-        dense[ectx, col] = evals  # combined keys are unique: plain assignment
-    with timer.span("phase2/device"):
-        incl = device.inclusive(dense)
     with timer.span("phase2/support"):
         ir, ic = _subtree_support(ectx, col, m, parent)
-        ivals = incl[ir, ic]
+    with timer.span("phase2/densify"):
+        i32 = np.int32
+        request = (ectx.astype(i32), col.astype(i32), evals.astype(np.float32),
+                   m, ir.astype(i32), ic.astype(i32))
+    with timer.span("phase2/device"):
+        ivals = device.inclusive_at(*request)
+    with timer.span("phase2/support"):
         nz = ivals != 0.0
         ir, ic = ir[nz], ic[nz]
         ikeys = ir * (1 << _KEY_SHIFT) + (prof_mids[ic] | INCLUSIVE_BIT)
@@ -307,7 +309,7 @@ def fused_transform(
     parent = np.asarray(parent, np.int64)
     if device is not None:
         ikeys, ivals = _inclusive_device(ectx, evals, col, m, prof_mids,
-                                         parent, end, device)
+                                         parent, device)
         return _assemble_final(ekeys, evals, ikeys, ivals, keep_exclusive)
     u = np.count_nonzero(np.diff(ectx, prepend=-1))  # distinct touched ctxs
     if n * m <= DENSE_SMALL or u >= max(1, int(n * DENSE_FRACTION)):

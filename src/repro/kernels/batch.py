@@ -10,11 +10,14 @@ many distinct profile shapes stream through.  Three hot loops route here:
 * **inclusive propagation** — the O(n_ctx x m) cumsum of the fused kernel
   becomes a batched :func:`repro.kernels.ops.inclusive_from_exclusive`
   launch: all profiles share the unified tree's preorder length ``n``, so
-  their dense exclusive matrices concatenate along columns into one
-  ``(n, M_total)`` blockscan.  Prefix sums are column-independent, so a
-  profile's result is a pure function of its own columns — **batch
-  composition cannot perturb bytes**, which is what keeps the device path
-  deterministic across executors and shard counts.
+  their exclusive matrices sit side by side in columns of one
+  ``(n, M_total)`` blockscan.  A profile ships only its non-zeros, as
+  (row, column, value) triplets, and the (row, column) pairs it wants
+  back; the device builds the matrix and returns the sums at those pairs.
+  Prefix sums are column-independent, so a profile's result is a pure
+  function of its own columns — **batch composition cannot perturb
+  bytes**, which is what keeps the device path deterministic across
+  executors and shard counts.
 * **duplicate-key combine** — the stable-sorted segment sums behind
   :func:`repro.core.pipeline._combine_sorted` dispatch to the ``segstats``
   one-hot MXU kernel.  These launch per-profile (never concatenated:
@@ -63,6 +66,12 @@ SUBLANE = 8    # second-minor tile multiple (f32)
 # of the plane (executor/batch independent)
 DEVICE_COMBINE_MIN = 4096
 
+# floors of the sparse propagation's shape classes: one launch of up to
+# four PeleC(1+82) profiles (<= 748 values, <= 1,114 support pairs each)
+# fits one triplet class and one support class at every column class
+TRIPLET_FLOOR = 4096
+SUPPORT_FLOOR = 8192
+
 # f32 integer-exactness ceiling: 2^24 (see module docstring)
 _EXACT_LIMIT = 2.0 ** 24
 
@@ -99,13 +108,34 @@ def _bucket(x: int, floor: int) -> int:
 
 
 class _Request:
-    __slots__ = ("cols", "out", "err", "event")
+    __slots__ = ("out", "err", "event")
 
-    def __init__(self, cols: np.ndarray):
-        self.cols = cols
+    def __init__(self):
         self.out: np.ndarray | None = None
         self.err: BaseException | None = None
         self.event = threading.Event()
+
+
+class _DenseRequest(_Request):
+    """A dense (n, m) exclusive matrix; its (n, m) inclusive sums back."""
+    __slots__ = ("cols",)
+
+    def __init__(self, cols: np.ndarray):
+        super().__init__()
+        self.cols = cols
+
+
+class _SparseRequest(_Request):
+    """The non-zeros of an (n, width) exclusive matrix as ``rows``,
+    ``cols`` and ``vals``; its inclusive sums back at the pairs
+    ``(ir, ic)`` only."""
+    __slots__ = ("width", "rows", "cols", "vals", "ir", "ic")
+
+    def __init__(self, width, rows, cols, vals, ir, ic):
+        super().__init__()
+        self.width = int(width)
+        self.rows, self.cols, self.vals = rows, cols, vals
+        self.ir, self.ic = ir, ic
 
 
 class DeviceAggregator:
@@ -128,6 +158,7 @@ class DeviceAggregator:
 
         from repro.kernels import ops
 
+        self._jax = jax
         self._jnp = jnp
         self._ops = ops
         self.timer = timer if timer is not None else PhaseTimer()
@@ -139,7 +170,8 @@ class DeviceAggregator:
         with self.timer.span("device/h2d"):
             self._end_dev = jnp.asarray(end32)
         self.timer.add("device_h2d_bytes", end32.nbytes)
-        self._incl_fn = jax.jit(ops.inclusive_from_exclusive)
+        self._incl_fn = jax.jit(ops.inclusive_from_exclusive,
+                                static_argnames="columns")
         self.interpret = not has_accelerator()
         # the one-hot combine is MXU free-lunch on hardware but O(n*S) host
         # work under the interpret proxy, so it defaults off there; tests
@@ -166,7 +198,21 @@ class DeviceAggregator:
         """``out[i, c] = sum(cols[i:end[i], c])`` for each column — the
         preorder-interval inclusive sums, f32.  Thread-safe; concurrent
         callers' columns ride one launch."""
-        req = _Request(np.ascontiguousarray(cols, dtype=np.float32))
+        return self._submit(
+            _DenseRequest(np.ascontiguousarray(cols, dtype=np.float32)))
+
+    def inclusive_at(self, rows: np.ndarray, cols: np.ndarray,
+                     vals: np.ndarray, width: int, ir: np.ndarray,
+                     ic: np.ndarray) -> np.ndarray:
+        """:meth:`inclusive` of the (n, ``width``) matrix whose non-zeros
+        are ``vals`` at ``(rows, cols)`` (unique pairs), read at the pairs
+        ``(ir, ic)`` only: one f32 value a pair.  Only the triplets and the
+        pairs cross the link, as int32 and f32; the device builds the
+        matrix, at the shape and with the bits the host would have built
+        it.  Thread-safe; concurrent callers ride one launch."""
+        return self._submit(_SparseRequest(width, rows, cols, vals, ir, ic))
+
+    def _submit(self, req: _Request) -> np.ndarray:
         with self._lock:
             self._pending.append(req)
             self.requests += 1
@@ -190,22 +236,29 @@ class DeviceAggregator:
 
     def _launch(self, batch: list[_Request]) -> None:
         try:
-            widths = [r.cols.shape[1] for r in batch]
-            with self.timer.span("device/pack"):
-                mat = (batch[0].cols if len(batch) == 1
-                       else np.concatenate([r.cols for r in batch], axis=1))
-            out = self._inclusive_padded(mat)
-            self.inclusive_launches += 1
-            o = 0
-            for r, w in zip(batch, widths):
-                r.out = out[:, o:o + w]
-                o += w
+            for kind, launch in ((_DenseRequest, self._launch_dense),
+                                 (_SparseRequest, self._launch_sparse)):
+                requests = [r for r in batch if isinstance(r, kind)]
+                if requests:
+                    launch(requests)
+                    self.inclusive_launches += 1
         except BaseException as e:
             for r in batch:
                 r.err = e
         finally:
             for r in batch:
                 r.event.set()
+
+    def _launch_dense(self, batch: list[_DenseRequest]) -> None:
+        widths = [r.cols.shape[1] for r in batch]
+        with self.timer.span("device/pack"):
+            mat = (batch[0].cols if len(batch) == 1
+                   else np.concatenate([r.cols for r in batch], axis=1))
+        out = self._inclusive_padded(mat)
+        o = 0
+        for r, w in zip(batch, widths):
+            r.out = out[:, o:o + w]
+            o += w
 
     def _inclusive_padded(self, mat: np.ndarray) -> np.ndarray:
         timer = self.timer
@@ -228,6 +281,56 @@ class DeviceAggregator:
         timer.add("device_columns", m)
         timer.add("device_padded_columns", mb)
         return out[:, :m]
+
+    def _launch_sparse(self, batch: list[_SparseRequest]) -> None:
+        """One launch of every request's triplets, side by side in columns
+        as :meth:`_launch_dense` concatenates matrices: request k's column
+        indices move by the widths of the requests before it.  Each array
+        is padded to its power-of-two class; a padding triplet points past
+        the last row and is dropped, a padding pair reads (0, 0) and is
+        discarded."""
+        timer = self.timer
+        with timer.span("device/pack"):
+            col0 = np.cumsum([0] + [r.width for r in batch])
+            nt = sum(r.rows.size for r in batch)
+            ns = sum(r.ir.size for r in batch)
+            m = int(col0[-1])
+            mb = _bucket(m, SUBLANE)
+            tb = _bucket(nt, TRIPLET_FLOOR)
+            sb = _bucket(ns, SUPPORT_FLOOR)
+            rows = np.full(tb, self.n, dtype=np.int32)
+            cols = np.zeros(tb, dtype=np.int32)
+            vals = np.zeros(tb, dtype=np.float32)
+            ir = np.zeros(sb, dtype=np.int32)
+            ic = np.zeros(sb, dtype=np.int32)
+            t = s = 0
+            for r, c0 in zip(batch, col0):
+                k = r.rows.size
+                rows[t:t + k], vals[t:t + k] = r.rows, r.vals
+                cols[t:t + k] = r.cols + c0
+                t += k
+                k = r.ir.size
+                ir[s:s + k] = r.ir
+                ic[s:s + k] = r.ic + c0
+                s += k
+        with timer.span("device/h2d"):
+            x = self._jax.device_put((rows, cols, vals, ir, ic))
+        with timer.span("device/kernel"):
+            y = self._incl_fn(x[:3], self._end_dev, x[3:],
+                              columns=mb).block_until_ready()
+        with timer.span("device/d2h"):
+            out = np.asarray(y)
+        timer.add("device_h2d_bytes", sum(a.nbytes for a in
+                                          (rows, cols, vals, ir, ic)))
+        timer.add("device_d2h_bytes", out.nbytes)
+        timer.add("device_values_in", nt)
+        timer.add("device_values_out", ns)
+        timer.add("device_columns", m)
+        timer.add("device_padded_columns", mb)
+        s = 0
+        for r in batch:
+            r.out = out[s:s + r.ir.size]
+            s += r.ir.size
 
     # -- duplicate-key combine (per-profile segment sums) --------------------
 

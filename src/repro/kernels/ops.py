@@ -162,9 +162,24 @@ def int8_dequant(q: jax.Array, scales: jax.Array, n: int,
 
 # -- composite: the propagation primitive (paper §4.1.2, DESIGN.md §4) -------
 
-def inclusive_from_exclusive(dense_preorder: jax.Array, end: jax.Array) -> jax.Array:
-    """inclusive[i] = cumsum[end[i]] - cumsum[i] over preorder values (N, M)."""
-    inc = blockscan(dense_preorder)
-    ps = jnp.concatenate([jnp.zeros((1, dense_preorder.shape[1]), inc.dtype), inc])
-    n = dense_preorder.shape[0]
-    return ps[end] - ps[jnp.arange(n)]
+def inclusive_from_exclusive(exclusive, end: jax.Array, at=None,
+                             columns: int | None = None) -> jax.Array:
+    """inclusive[i] = cumsum[end[i]] - cumsum[i] over preorder values (N, M).
+
+    ``exclusive`` is the (N, M) matrix, or with ``columns`` = M its
+    non-zeros as ``(rows, cols, vals)``: the matrix is then built here, and
+    an entry whose row is out of range (a padding sentinel) is dropped.
+    With ``at`` = ``(ir, ic)`` only ``inclusive[ir, ic]`` is returned.
+    ``columns`` is static under ``jax.jit``."""
+    if columns is not None:
+        rows, cols, vals = exclusive
+        exclusive = jnp.zeros((end.shape[0], columns), vals.dtype).at[
+            rows, cols].set(vals, mode="drop")
+    n = exclusive.shape[0]
+    inc = blockscan(exclusive)
+    ps = jnp.concatenate([jnp.zeros((1, exclusive.shape[1]), inc.dtype), inc])
+    incl = ps[end] - ps[jnp.arange(n)]
+    if at is None:
+        return incl
+    ir, ic = at
+    return incl[ir, ic]

@@ -110,23 +110,64 @@ def test_blockscan_dtypes(rng, dtype):
     assert_allclose(got, np.cumsum(x, axis=0), rtol=1e-3, atol=1e-4)
 
 
-def test_inclusive_from_exclusive_matches_tree_walk(rng):
-    from repro.core.propagate import propagate_inclusive
-    from repro.core.sparse import SparseMetrics
-    from tests.conftest import random_sparse, random_tree
-    t = random_tree(rng, 64)
-    sm = random_sparse(rng, len(t), 4, 0.2)
-    pos, order, end = t.preorder()
-    dense = sm.to_dense(len(t), 4)[order].astype(np.float32)
-    incl = np.asarray(ops.inclusive_from_exclusive(
-        jnp.asarray(dense), jnp.asarray(end)))
-    oracle = propagate_inclusive(sm, pos, end, keep_exclusive=False)
+def _tree_walk_case(rng, n_nodes=64, n_metrics=4):
+    """A random tree's preorder ``end``, a sparse plane on it as preorder
+    (row, column, value) triplets, and the tree-walk oracle's inclusive
+    values by (row, column)."""
     from repro.core.metrics import INCLUSIVE_BIT
+    from repro.core.propagate import propagate_inclusive
+    from tests.conftest import random_sparse, random_tree
+    t = random_tree(rng, n_nodes)
+    sm = random_sparse(rng, len(t), n_metrics, 0.2)
+    pos, order, end = t.preorder()
+    ctx, mid, val = sm.triplets()
+    oracle = propagate_inclusive(sm, pos, end, keep_exclusive=False)
+    incl = {}
     for k in range(oracle.n_contexts):
         c = int(oracle.ctx[k])
-        mids, vals = oracle.context_slice(c)
-        for m, v in zip(mids, vals):
-            assert incl[pos[c], int(m) & ~INCLUSIVE_BIT] == pytest.approx(v, rel=1e-4)
+        for m, v in zip(*oracle.context_slice(c)):
+            incl[int(pos[c]), int(m) & ~INCLUSIVE_BIT] = v
+    return end, (pos[ctx], mid.astype(np.int64), val), incl
+
+
+def test_inclusive_from_exclusive_matches_tree_walk(rng):
+    end, (rows, cols, vals), incl = _tree_walk_case(rng)
+    dense = np.zeros((end.size, 4), np.float32)
+    dense[rows, cols] = vals
+    got = np.asarray(ops.inclusive_from_exclusive(
+        jnp.asarray(dense), jnp.asarray(end)))
+    for (r, c), v in incl.items():
+        assert got[r, c] == pytest.approx(v, rel=1e-4)
+
+
+@pytest.mark.parametrize("pad", [0, 37])
+@pytest.mark.parametrize("support", ["all", "random", "empty"])
+def test_sparse_inclusive_matches_tree_walk(rng, support, pad):
+    """The triplet form: the device builds the matrix from the non-zeros,
+    drops padding triplets that point past the last row, and returns the
+    inclusive sums at the asked pairs only."""
+    n_metrics = 4
+    end, (rows, cols, vals), incl = _tree_walk_case(rng, n_metrics=n_metrics)
+    n = end.size
+    rows = np.concatenate([rows, np.full(pad, n)])       # sentinels
+    cols = np.concatenate([cols, rng.integers(0, n_metrics, pad)])
+    vals = np.concatenate([vals, rng.uniform(1, 9, pad)])
+    if support == "all":
+        ir, ic = np.divmod(np.arange(n * n_metrics), n_metrics)
+    elif support == "random":
+        ir = rng.integers(0, n, 50)
+        ic = rng.integers(0, n_metrics, 50)
+    else:
+        ir = ic = np.zeros(0, np.int64)
+    i32 = jnp.int32
+    got = np.asarray(ops.inclusive_from_exclusive(
+        (jnp.asarray(rows, i32), jnp.asarray(cols, i32),
+         jnp.asarray(vals, jnp.float32)),
+        jnp.asarray(end, i32), (jnp.asarray(ir, i32), jnp.asarray(ic, i32)),
+        columns=n_metrics))
+    assert got.shape == ir.shape
+    want = [incl.get((int(r), int(c)), 0.0) for r, c in zip(ir, ic)]
+    assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -288,25 +329,90 @@ def test_device_aggregator_inclusive_matches_numpy(rng):
     assert dev.launches == 1 and dev.requests == 1
 
 
-def test_device_aggregator_coalesces_concurrent_requests(rng):
+def _sparse_request(rng, n, width, k):
+    """Unique random (row, column) triplets of integer values over an
+    (n, width) matrix, and ``k`` random pairs to read back."""
+    flat = rng.choice(n * width, size=min(n * width, 3 * width), replace=False)
+    rows, cols = np.divmod(flat, width)
+    vals = rng.integers(1, 9, flat.size).astype(np.float32)
+    ir, ic = rng.integers(0, n, k), rng.integers(0, width, k)
+    return rows, cols, vals, width, ir, ic
+
+
+@pytest.mark.parametrize("width", [1, 5, 9, 82])
+def test_sparse_launch_equals_dense_launch_bitwise(rng, width):
+    """f32-class values: the triplet launch builds on the device the
+    matrix the host would build and scans it at the same shape, so its
+    values at the pairs are the dense launch's, bit for bit."""
+    n = 300
+    end = np.sort(rng.integers(1, n + 1, n))[::-1].copy()
+    end = np.maximum(end, np.arange(n) + 1)
+    dev = kb.DeviceAggregator(end)
+    rows, cols, _, _, ir, ic = _sparse_request(rng, n, width, 400)
+    vals = rng.normal(size=rows.size).astype(np.float32)
+    dense = np.zeros((n, width), np.float32)
+    dense[rows, cols] = vals
+    got = dev.inclusive_at(rows, cols, vals, width, ir, ic)
+    want = dev.inclusive(dense)[ir, ic]
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
+
+
+@pytest.mark.parametrize("kind", ["dense", "sparse"])
+def test_device_aggregator_coalesces_concurrent_requests(rng, kind):
     """Threads racing into the combining funnel must each get exactly their
-    own columns back, with (usually) fewer launches than requests."""
+    own values back.  The first launch is held until the other five
+    requests wait, so those five share the second.  Sparse requests of
+    mixed widths (1, 81, 82 columns, as CPU ranks and GPU streams) get
+    back what each gets alone, whatever shared its launch."""
     import threading
+    import time
     n, n_threads = 64, 6
     end = _chain_end(n)
     dev = kb.DeviceAggregator(end)
     dev.inclusive(np.zeros((n, 1), np.float32))  # warm the jit cache
+    if kind == "dense":
+        reqs = [np.full((n, k + 1), float(k + 1), dtype=np.float32)
+                for k in range(n_threads)]
+        submit = dev.inclusive
+        # chain tree: inclusive[i] = sum over [i, n) = (n - i) * v
+        wants = [np.outer(n - np.arange(n), np.ones(k + 1)) * (k + 1)
+                 for k in range(n_threads)]
+    else:
+        reqs = [_sparse_request(rng, n, (1, 81, 82)[k % 3], 40 + k)
+                for k in range(n_threads)]
+        submit = dev.inclusive_at
+        wants = [submit(*r) for r in reqs]   # each alone in its launch
+        for (rows, cols, vals, width, ir, ic), want in zip(reqs, wants):
+            dense = np.zeros((n + 1, width))
+            dense[rows, cols] = vals
+            ps = np.cumsum(dense[::-1], axis=0)[::-1]   # sum over [i, n)
+            np.testing.assert_array_equal(want, ps[ir, ic])
     barrier = threading.Barrier(n_threads)
     outs, errs = [None] * n_threads, [None] * n_threads
 
     def work(k):
-        cols = np.full((n, k + 1), float(k + 1), dtype=np.float32)
         barrier.wait()
         try:
-            outs[k] = dev.inclusive(cols)
+            outs[k] = submit(*reqs[k]) if kind == "sparse" else \
+                submit(reqs[k])
         except BaseException as e:  # pragma: no cover - surfaced below
             errs[k] = e
 
+    fn = dev._incl_fn
+    held = []
+
+    def hold_first_launch(*a, **kw):
+        # the first launch waits until every other request is pending, so
+        # that those share the next one
+        if not held:
+            held.append(True)
+            while len(dev._pending) < n_threads - 1:
+                time.sleep(0.001)
+        return fn(*a, **kw)
+
+    dev._incl_fn = hold_first_launch
+    requests, launches = dev.requests, dev.launches
     threads = [threading.Thread(target=work, args=(k,))
                for k in range(n_threads)]
     for t in threads:
@@ -315,12 +421,13 @@ def test_device_aggregator_coalesces_concurrent_requests(rng):
         t.join()
     assert errs == [None] * n_threads
     for k in range(n_threads):
-        # chain tree: inclusive[i] = sum over [i, n) = (n - i) * v
-        want = np.outer(n - np.arange(n), np.ones(k + 1)) * (k + 1)
-        assert outs[k].shape == (n, k + 1)
-        assert_allclose(outs[k], want, rtol=1e-6)
-    assert dev.requests == n_threads + 1
-    assert dev.launches <= dev.requests
+        assert outs[k].shape == wants[k].shape
+        if kind == "dense":
+            assert_allclose(outs[k], wants[k], rtol=1e-6)
+        else:
+            np.testing.assert_array_equal(outs[k], wants[k])
+    assert dev.requests == requests + n_threads
+    assert dev.launches == launches + 2
 
 
 def test_device_aggregator_combine_sums_matches_bincount(rng):

@@ -507,6 +507,41 @@ def test_fused_device_bytes_equal_cpu_on_exact_planes(seed):
     assert cpu.encode() == out.encode()
 
 
+def _inclusive_device_dense(ectx, evals, col, m, prof_mids, parent, device):
+    """The device propagation as it was before triplets, the oracle of the
+    test below: the (n, m) f32 matrix built on the host, the dense launch,
+    and the gather at the subtree support on the host."""
+    from repro.core import pipeline
+    dense = np.zeros((device.n, m), dtype=np.float32)
+    dense[ectx, col] = evals
+    incl = device.inclusive(dense)
+    ir, ic = pipeline._subtree_support(ectx, col, m, parent)
+    ivals = incl[ir, ic]
+    nz = ivals != 0.0
+    ir, ic = ir[nz], ic[nz]
+    ikeys = ir * (1 << pipeline._KEY_SHIFT) + (prof_mids[ic] | INCLUSIVE_BIT)
+    return ikeys, ivals[nz].astype(np.float64)
+
+
+@settings(max_examples=20, deadline=None)
+@given(st.integers(0, 2**31 - 1))
+def test_fused_device_bytes_equal_dense_launch_on_f32_planes(seed):
+    """f32-class planes, routes included: shipping the triplets and reading
+    back the support gives the plane the dense launch gave, byte for byte
+    (the same matrix, scanned at the same shape, read at the same pairs)."""
+    from repro.core import pipeline
+    from repro.kernels.batch import classify_plane
+    rng = np.random.default_rng(seed)
+    sm, remap, routes, parent_pre, end, n = _random_tree_case(rng)
+    assert sm.n_values == 0 or classify_plane(sm.triplets()[2]) == "f32"
+    dev = _device_aggregator(end)
+    out = fused_transform(sm, remap, routes, parent_pre, end, device=dev)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pipeline, "_inclusive_device", _inclusive_device_dense)
+        want = fused_transform(sm, remap, routes, parent_pre, end, device=dev)
+    assert out.encode() == want.encode()
+
+
 def test_device_path_edge_cases(rng):
     """Empty profile, single metric, and all-placeholder planes must all
     survive the device dispatch."""

@@ -107,11 +107,12 @@ def test_phase2_leaf_spans_cover_its_wall(tmp_path, rng, compute):
 
 def test_transfer_counters_equal_the_launched_bytes(tmp_path, rng,
                                                     monkeypatch):
-    """Every propagation launch ships its padded (rows x columns) float32
-    matrix both ways; besides it, the tree's int32 ``end`` array goes to
-    the device once and the CMS offset scan ships its sizes and reads its
-    offsets back (the census and the combine stay on the host under the
-    interpret proxy)."""
+    """Every propagation launch ships its padded (row, column, value)
+    triplets and support pairs, and reads one float32 back a pair; besides
+    them, the tree's int32 ``end`` array goes to the device once and the
+    CMS offset scan ships its sizes and reads its offsets back (the census
+    and the combine stay on the host under the interpret proxy).  The value
+    counters count the triplets and the pairs before padding."""
     from repro.kernels.batch import DeviceAggregator
 
     launched = []
@@ -121,9 +122,9 @@ def test_transfer_counters_equal_the_launched_bytes(tmp_path, rng,
         init(self, *a, **kw)
         fn = self._incl_fn
 
-        def spy(x, end):
-            launched.append(x.shape)
-            return fn(x, end)
+        def spy(exclusive, end, at=None, columns=None):
+            launched.append((exclusive, at, columns))
+            return fn(exclusive, end, at, columns=columns)
         self._incl_fn = spy
 
     monkeypatch.setattr(DeviceAggregator, "__init__", spy_init)
@@ -133,16 +134,24 @@ def test_transfer_counters_equal_the_launched_bytes(tmp_path, rng,
     res = StreamingAggregator(tmp_path / "db", cfg).run(paths)
     t, n = res.timings, res.n_contexts
     assert len(launched) == t["device_inclusive_launches"] > 0
-    assert all(rows == n for rows, _ in launched)
-    matrices = sum(4 * rows * cols for rows, cols in launched)
-    assert t["device_h2d_bytes"] == matrices + 4 * n + 4 * n
-    assert t["device_d2h_bytes"] == matrices + 4 * (n + 1)
-    assert t["device_padded_columns"] == sum(c for _, c in launched)
-    # one column per distinct exclusive metric of each profile's plane
+    assert all(columns is not None and at is not None
+               for _, at, columns in launched)   # never a dense matrix
+    shipped = sum(a.nbytes for exc, at, _ in launched for a in (*exc, *at))
+    assert t["device_h2d_bytes"] == shipped + 4 * n + 4 * n
+    pairs = sum(at[0].size for _, at, _ in launched)
+    assert t["device_d2h_bytes"] == 4 * pairs + 4 * (n + 1)
+    assert t["device_padded_columns"] == sum(c for _, _, c in launched)
+    assert t["device_values_in"] <= sum(exc[0].size for exc, _, _ in launched)
+    assert t["device_values_out"] <= pairs
+    # one column per distinct exclusive metric of each profile's plane, one
+    # triplet per exclusive value, a support pair per inclusive value at least
     with PMSReader(res.pms_path) as pms:
         mids = [pms.plane(p).mid for p in range(pms.n_profiles)]
-        assert t["device_columns"] == sum(
-            np.unique(m[m < INCLUSIVE_BIT]).size for m in mids)
+    excl = [m[m < INCLUSIVE_BIT] for m in mids]
+    assert t["device_columns"] == sum(np.unique(m).size for m in excl)
+    assert t["device_values_in"] == sum(m.size for m in excl)
+    assert t["device_values_out"] >= sum(
+        np.count_nonzero(m >= INCLUSIVE_BIT) for m in mids)
 
 
 def test_spans_share_the_device_trace_clock(tmp_path, rng):

@@ -74,6 +74,28 @@ def test_blockscan_f32_propagation_compiles(one_chip, m):
              _arg((N_CTX,), jnp.int32, one_chip))
 
 
+# the benchmark's PeleC(1+82) fleet: its unified tree, and the triplet and
+# support-pair classes of a propagation launch (batch.TRIPLET_FLOOR,
+# batch.SUPPORT_FLOOR and the next class up)
+BENCH_CTX = 68_497
+
+
+@pytest.mark.parametrize("buckets", [(4096, 8192), (8192, 8192)])
+@pytest.mark.parametrize("m", [8, 128, 256, 512])
+def test_sparse_propagation_compiles(one_chip, m, buckets):
+    """The propagation as the analysis launches it: (row, column, value)
+    triplets in, the matrix built and scanned on the device, the inclusive
+    sums at the support pairs out, at every column class of the fleet."""
+    nt, ns = buckets
+    i32 = jnp.int32
+    _compile(ops.inclusive_from_exclusive,
+             (_arg((nt,), i32, one_chip), _arg((nt,), i32, one_chip),
+              _arg((nt,), jnp.float32, one_chip)),
+             _arg((BENCH_CTX,), i32, one_chip),
+             (_arg((ns,), i32, one_chip), _arg((ns,), i32, one_chip)),
+             columns=m)
+
+
 def test_blockscan_int32_offsets_compile(one_chip):
     """The CMS stripe offsets: an int32 exclusive scan over every context."""
     _compile(ops.exclusive_scan, _arg((N_CTX,), jnp.int32, one_chip))
