@@ -18,10 +18,38 @@ repository's ``benchmarks/workloads.generate``, drawn in bulk:
   1) distinct metrics drawn uniformly from its pool;
 * values are exponential costs (mean 1).
 
+A configuration may also give its binaries' structure (paper §4.1.1 and
+§4.1.3; Zhou et al., "Measurement and Analysis of GPU-Accelerated
+Applications with HPCToolkit", Parallel Computing 108, 2021).  The three
+keys are optional, and their absence means "no structure":
+
+* ``struct_scopes`` (default 0): lexical scopes on every structure path,
+  the scopes a binary's structure places between an op's parent and the op,
+  outermost first: inlined functions (``KIND_MODULE``) and loops
+  (``KIND_LOOP``) in turn, each named from a pool of ``SCOPE_POOL`` names a
+  binary.  With it, the fleet has a CPU binary and a GPU binary, each with a
+  structure file naming every op of the application tree: CPU profiles
+  name the CPU binary's file in their ``file_paths``, GPU-stream profiles
+  the GPU binary's;
+* ``routed_share`` (default 0): the share of the GPU binary's ops,
+  ``int(n_ops * routed_share)`` of them, that have several call routes;
+* ``n_routes`` (default 0; at least 2 where ``routed_share`` is set):
+  routes per routed op, each with its own scope path (their outermost
+  scopes differ) and an integer call-sample weight from 1 to ``MAX_WEIGHT``.
+  An op with one route has the weight 1.
+
+The structure is drawn after every profile, from a generator of its own,
+``default_rng([seed, 1])``, so a configuration without these keys draws
+exactly the numbers, and writes exactly the bytes, it did before they
+existed.
+
 The profiles are written in the ``.rprf`` format that ``repro.launch.analyze``
 reads (magic ``RPRF``, version 1, a JSON block, the tree arrays, an empty
-trace and the sparse metrics), by this module alone.  :class:`Fleet` keeps
-every profile's tree and triplets in memory for the plain reference.
+trace and the sparse metrics), by this module alone; the structure files
+beside them in the JSON format of ``repro.core.lexical``
+(``{"binary": ..., "ops": {op: [{"path": [[kind, name], ...],
+"weight": w}, ...]}}``).  :class:`Fleet` keeps every profile's tree,
+triplets and structure in memory for the plain reference.
 """
 from __future__ import annotations
 
@@ -33,12 +61,16 @@ from dataclasses import dataclass
 
 import numpy as np
 
-KIND_ROOT, KIND_PHASE, KIND_MODULE, KIND_OP, KIND_LINE = 0, 1, 2, 4, 5
+KIND_ROOT, KIND_PHASE, KIND_MODULE, KIND_LOOP, KIND_OP, KIND_LINE = \
+    0, 1, 2, 3, 4, 5
 N_PHASES, N_MODULES = 3, 24
 PHASES = ("main", "solve", "comm")
+SCOPE_POOL = 32      # inlined-function and loop names a binary
+MAX_WEIGHT = 1000    # call samples of a route, at most
 
 SHAPE_KEYS = ("n_profiles", "n_ctx", "n_cpu_metrics", "n_gpu_metrics",
               "ctx_density", "met_density", "n_private")
+STRUCT_KEYS = ("struct_scopes", "routed_share", "n_routes")
 
 
 @dataclass(frozen=True)
@@ -51,10 +83,21 @@ class FleetShape:
     ctx_density: float
     met_density: float
     n_private: int = 0
+    struct_scopes: int = 0
+    routed_share: float = 0.0
+    n_routes: int = 0
+
+    def __post_init__(self):
+        if self.routed_share and not (
+                self.struct_scopes >= 1 and 2 <= self.n_routes <= SCOPE_POOL):
+            raise ValueError(
+                f"{self.name}: routed_share needs struct_scopes >= 1 and "
+                f"2 <= n_routes <= {SCOPE_POOL}")
 
     @classmethod
     def from_config(cls, cfg: dict) -> "FleetShape":
-        return cls(cfg["name"], *(cfg[k] for k in SHAPE_KEYS))
+        return cls(cfg["name"], *(cfg[k] for k in SHAPE_KEYS),
+                   **{k: cfg[k] for k in STRUCT_KEYS if k in cfg})
 
     @property
     def n_metrics(self) -> int:
@@ -81,12 +124,26 @@ class Tree:
 
 
 @dataclass
+class Structure:
+    """One binary's structure file: op name -> its routes, each
+    ``{"path": [[kind, name], ...], "weight": w}`` with the path outermost
+    first."""
+    binary: str
+    ops: dict[str, list[dict]]
+
+    def encode(self) -> bytes:
+        return json.dumps({"binary": self.binary, "ops": self.ops},
+                          separators=(",", ":")).encode("utf-8")
+
+
+@dataclass
 class Profile:
     tree: Tree
     ctx: np.ndarray    # (x,) int64 local context ids, sorted with mid
     mid: np.ndarray    # (x,) int64 metric ids
     val: np.ndarray    # (x,) float64, non-zero
     identity: dict
+    structure: Structure | None = None   # its binary's, named in file_paths
 
 
 @dataclass
@@ -152,6 +209,47 @@ def _draw_metrics(rng, n_live: int, pool: np.ndarray, k: int) -> np.ndarray:
     return pool[np.argsort(rng.random((n_live, pool.size)), axis=1)[:, :k]]
 
 
+def _scope_paths(binary: str, idx: np.ndarray) -> list[list[list]]:
+    """One path per row of scope-name indices: inlined functions and loops
+    in turn, outermost first."""
+    kinds = [(KIND_MODULE, "inl") if j % 2 == 0 else (KIND_LOOP, "loop")
+             for j in range(idx.shape[1])]
+    return [[[k, f"{binary}.{tag}{i}"] for (k, tag), i in zip(kinds, row)]
+            for row in idx.tolist()]
+
+
+def draw_structures(shape: FleetShape, ops: list[str], seed: int
+                    ) -> tuple[Structure, Structure]:
+    """The CPU and the GPU binary's structure over the op names ``ops``,
+    from a generator of their own."""
+    rng = np.random.default_rng([seed, 1])
+    d, n = shape.struct_scopes, len(ops)
+    cpu_paths = _scope_paths("cpu", rng.integers(0, SCOPE_POOL, (n, d)))
+    cpu = Structure(f"{shape.name}.cpu", {
+        op: [{"path": path, "weight": 1}] for op, path in zip(ops, cpu_paths)})
+
+    k = shape.n_routes if shape.routed_share else 1
+    routed = np.sort(rng.choice(n, int(n * shape.routed_share), replace=False))
+    n_routes = np.ones(n, np.int64)
+    n_routes[routed] = k
+    idx = rng.integers(0, SCOPE_POOL, (int(n_routes.sum()), d))
+    # the routes of one op differ in their outermost scope (its call site)
+    first = np.argsort(rng.random((routed.size, SCOPE_POOL)), axis=1)[:, :k]
+    start = np.cumsum(n_routes) - n_routes
+    if routed.size:
+        idx[(start[routed][:, None] + np.arange(k)).reshape(-1), 0] = \
+            first.reshape(-1)
+    weights = np.ones(idx.shape[0], np.int64)
+    weights[(start[routed][:, None] + np.arange(k)).reshape(-1)] = \
+        rng.integers(1, MAX_WEIGHT + 1, routed.size * k)
+    paths = _scope_paths("gpu", idx)
+    gpu = Structure(f"{shape.name}.gpu", {
+        op: [{"path": paths[r], "weight": int(weights[r])}
+             for r in range(start[i], start[i] + n_routes[i])]
+        for i, op in enumerate(ops)})
+    return cpu, gpu
+
+
 def make_fleet(shape: FleetShape, seed: int) -> list[Profile]:
     """Every profile of ``shape`` drawn from ``seed``, in memory."""
     rng = np.random.default_rng(seed)
@@ -176,6 +274,12 @@ def make_fleet(shape: FleetShape, seed: int) -> list[Profile]:
         profiles.append(Profile(
             tree, ctx[keep], mid[keep], val[keep],
             {"rank": p // 2, "stream": p % 2, "kind": "gpu" if gpu else "cpu"}))
+    if shape.struct_scopes:
+        ops = [n for n, k in zip(shared.names, shared.kind.tolist())
+               if k == KIND_OP]
+        cpu, gpu = draw_structures(shape, ops, seed)
+        for p, prof in enumerate(profiles):
+            prof.structure = gpu if shape.is_gpu(p) else cpu
     return profiles
 
 
@@ -197,7 +301,8 @@ def _json(obj) -> bytes:
     return struct.pack("<I", len(data)) + data
 
 
-def encode_profile(prof: Profile, app: str, n_metrics: int) -> bytes:
+def encode_profile(prof: Profile, app: str, n_metrics: int,
+                   file_paths: list[str] = ()) -> bytes:
     t = prof.tree
     names = np.frombuffer("\x00".join(t.names).encode("utf-8"), np.uint8)
     ctx, first = np.unique(prof.ctx, return_index=True)
@@ -205,7 +310,7 @@ def encode_profile(prof: Profile, app: str, n_metrics: int) -> bytes:
     return b"".join([
         b"RPRF", struct.pack("<I", 1),
         _json({"environment": {"app": app, "n_metrics": n_metrics},
-               "identity": prof.identity, "file_paths": []}),
+               "identity": prof.identity, "file_paths": list(file_paths)}),
         _array(t.parent.astype(np.int64)), _array(t.kind.astype(np.uint8)),
         _array(np.arange(len(t), dtype=np.uint32)), _array(names),
         _array(np.empty(0, np.float64)), _array(np.empty(0, np.uint32)),
@@ -215,13 +320,24 @@ def encode_profile(prof: Profile, app: str, n_metrics: int) -> bytes:
 
 
 def write_fleet(shape: FleetShape, seed: int, out_dir: str) -> Fleet:
-    """Draw the fleet and write one ``.rprf`` per profile under ``out_dir``."""
+    """Draw the fleet and write one ``.rprf`` per profile under ``out_dir``,
+    and each binary's ``<binary>.struct.json`` beside them; a profile names
+    its binary's file by its absolute path."""
     os.makedirs(out_dir, exist_ok=True)
     profiles = make_fleet(shape, seed)
+    struct_paths: dict[str, str] = {}
+    for s in (prof.structure for prof in profiles if prof.structure):
+        if s.binary not in struct_paths:
+            path = os.path.abspath(os.path.join(out_dir,
+                                                f"{s.binary}.struct.json"))
+            with open(path, "wb") as f:
+                f.write(s.encode())
+            struct_paths[s.binary] = path
     paths = []
     for p, prof in enumerate(profiles):
         path = os.path.join(out_dir, f"{shape.name}.{p:04d}.rprf")
+        named = [struct_paths[prof.structure.binary]] if prof.structure else []
         with open(path, "wb") as f:
-            f.write(encode_profile(prof, shape.name, shape.n_metrics))
+            f.write(encode_profile(prof, shape.name, shape.n_metrics, named))
         paths.append(path)
     return Fleet(shape, profiles, paths)

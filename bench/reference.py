@@ -2,13 +2,33 @@
 ``correct``.
 
 The reference follows the semantics of a postmortem analysis (paper §4.1)
-with nothing of the program: unify the profiles' trees by (parent, kind,
-name), number the unified tree in depth-first preorder with children in
-(kind, name) order, sum each profile's duplicate (context, metric) values
+with nothing of the program: expand each profile's tree with its binary's
+structure (below), unify the profiles' trees by (parent, kind, name),
+number the unified tree in depth-first preorder with children in (kind,
+name) order, sum each profile's duplicate (context, metric) values
 (exclusive), add every value to all of its context's ancestors
 (inclusive, bit 15 of the metric id), and take summary statistics of each
 (context, metric) over the profiles that hold it.  The context-major copy
 is the same values ordered by (context, metric, profile).
+
+Structure (paper §4.1.1, the "edit" of Fig. 3, and §4.1.3), as the fleet's
+structure files state it, one entry per op: its routes, each a path of
+lexical scopes and a call-sample weight.
+
+* An op the structure names with one route is placed under that route's
+  scopes, below the op's parent in the profile.
+* An op with several routes is a call site "in superposition": it becomes a
+  placeholder, ``<op>@superposition`` of kind ``KIND_ROUTE``, under its
+  parent, and one op leaf hangs under each route's scopes, below the same
+  parent.  A value measured on the op goes to the leaves as
+  ``v * w / sum(w)`` before the exclusive combine, so the placeholder holds
+  no exclusive value of its own.
+* A profile without structure is unified as it is.
+
+Departures, where the paper does not say: the contexts below a routed op
+in the profile (its lines) hang under the placeholder and keep their own
+values, which are not split; the scopes of a route start at the op's
+parent in the profile, not at the root.
 
 ``build(fleet)`` computes it in float64; ``build(fleet, precision)`` computes
 the controls in a precision below the float32 the device path keeps at
@@ -26,6 +46,7 @@ from dataclasses import dataclass
 import numpy as np
 
 INCLUSIVE_BIT = 1 << 15
+KIND_OP, KIND_ROUTE = 4, 6
 U = 2.0 ** -24          # float32 unit roundoff: the gaps are in units of u
 
 # the numbers compared, each with its limit in the configuration file
@@ -87,6 +108,77 @@ def _precision(name: str):
     if name == "high":
         return _three_pass, np.float32
     raise ValueError(f"unknown precision {name!r}")
+
+
+@dataclass
+class Expanded:
+    """A profile's tree with its structure applied: ``where[local id]`` is
+    the node that takes the context's values, ``routes[placeholder]`` the
+    leaves and weights a placeholder's values go to."""
+    parent: np.ndarray
+    kind: np.ndarray
+    names: list[str]
+    where: np.ndarray
+    routes: dict[int, tuple[np.ndarray, np.ndarray]]
+
+    def __len__(self) -> int:
+        return int(self.parent.size)
+
+
+def expand(tree, structure) -> Expanded:
+    """``tree`` with the scopes and placeholders of ``structure`` (a table
+    op name -> routes) inserted; nodes may repeat, unification merges
+    them."""
+    if structure is None:
+        return Expanded(tree.parent, tree.kind, tree.names,
+                        np.arange(len(tree)), {})
+    parent, kind, names = [-1], [int(tree.kind[0])], [tree.names[0]]
+
+    def add(up: int, k: int, name: str) -> int:
+        parent.append(up)
+        kind.append(k)
+        names.append(name)
+        return len(parent) - 1
+
+    where = np.zeros(len(tree), np.int64)
+    routes = {}
+    tp, tk = tree.parent.tolist(), tree.kind.tolist()
+    for i in range(1, len(tree)):
+        up, k, name = int(where[tp[i]]), tk[i], tree.names[i]
+        entries = structure.get(name) if k == KIND_OP else None
+        if not entries:
+            where[i] = add(up, k, name)
+            continue
+        leaves = []
+        for e in entries:
+            node = up
+            for sk, sn in e["path"]:
+                node = add(node, int(sk), str(sn))
+            leaves.append(add(node, KIND_OP, name))
+        if len(leaves) == 1:
+            where[i] = leaves[0]
+        else:
+            where[i] = add(up, KIND_ROUTE, f"{name}@superposition")
+            routes[int(where[i])] = (
+                np.asarray(leaves, np.int64),
+                np.asarray([e["weight"] for e in entries], np.float64))
+    return Expanded(np.asarray(parent, np.int64), np.asarray(kind, np.uint8),
+                    names, where, routes)
+
+
+def split_routes(ctx, mid, val, routes):
+    """Values measured on a placeholder go to its route leaves, each
+    ``v * w / sum(w)``; the others stay where they are."""
+    if not routes:
+        return ctx, mid, val
+    at = np.isin(ctx, np.fromiter(routes, np.int64))
+    c, m, v = [ctx[~at]], [mid[~at]], [val[~at]]
+    for i in np.flatnonzero(at):
+        leaves, w = routes[int(ctx[i])]
+        c.append(leaves)
+        m.append(np.full(leaves.size, mid[i]))
+        v.append(val[i] * w / w.sum())
+    return np.concatenate(c), np.concatenate(m), np.concatenate(v)
 
 
 def unify(trees) -> tuple[np.ndarray, np.ndarray, list[str], list[np.ndarray]]:
@@ -178,7 +270,14 @@ def _stats(keys: np.ndarray, vals: np.ndarray, dtype) -> dict[str, np.ndarray]:
 def build(fleet, precision: str = "float64") -> Reference:
     inputs, dtype = _precision(precision)
     profiles = fleet.profiles
-    parent, kind, names, maps = unify([p.tree for p in profiles])
+    expanded, trees = {}, []    # one expansion per (tree, structure) pair
+    for prof in profiles:
+        key = (id(prof.tree), id(prof.structure))
+        if key not in expanded:
+            expanded[key] = expand(prof.tree, prof.structure and
+                                   prof.structure.ops)
+        trees.append(expanded[key])
+    parent, kind, names, maps = unify(trees)
     depth = np.zeros(parent.size, np.int64)
     for i in range(1, parent.size):       # parents precede children
         depth[i] = depth[parent[i]] + 1
@@ -186,8 +285,10 @@ def build(fleet, precision: str = "float64") -> Reference:
     scale = np.zeros((len(profiles), n_metrics))
     keys, vals = [], []
     for p, prof in enumerate(profiles):
-        c, m, v = _profile_plane(maps[p][prof.ctx], prof.mid, prof.val,
-                                 parent, depth, inputs, dtype)
+        t = trees[p]
+        c, m, v = split_routes(t.where[prof.ctx], prof.mid, prof.val, t.routes)
+        c, m, v = _profile_plane(maps[p][c], m, v, parent, depth, inputs,
+                                 dtype)
         keys.append(_pack(p, c, m))
         vals.append(v)
         excl = m < INCLUSIVE_BIT

@@ -14,7 +14,8 @@ for p in (str(ROOT / "src"), str(ROOT)):
         sys.path.insert(0, p)
 
 TINY = {"tiny-pelec": ROOT / "bench/tests/configs/tiny-pelec.json",
-        "tiny-amg": ROOT / "bench/tests/configs/tiny-amg.json"}
+        "tiny-amg": ROOT / "bench/tests/configs/tiny-amg.json",
+        "tiny-pelec-routes": ROOT / "bench/tests/configs/tiny-pelec-routes.json"}
 
 
 def tiny_benchmark(tmp_path: Path) -> Path:

@@ -28,7 +28,8 @@ def last_json(proc):
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("workload", ["tiny-pelec.analyze", "tiny-amg.analyze"])
+@pytest.mark.parametrize("workload", ["tiny-pelec.analyze", "tiny-amg.analyze",
+                                      "tiny-pelec-routes.analyze"])
 def test_end_to_end_metrics(benchmark_file, workload):
     proc = bench(benchmark_file, "--workload", workload, "--seed",
                  str(2**33 + 9), "--seconds", "2", "--trace", "0")
@@ -50,10 +51,14 @@ def test_traced_run_reports_per_layer_metrics(benchmark_file):
                  "12", "--seconds", "2", "--trace", "1")
     assert proc.returncode == 0, proc.stderr[-3000:]
     out = last_json(proc)
-    # the CPU backend has no device plane: no device metric, no breakdown
+    # the CPU backend has no device plane: every per-layer metric of the
+    # cell but the device trace's, and no breakdown
     assert list(out) == KEYS
-    assert set(out["metrics"]) == {"phase1_s", "phase2_s", "cms_s",
-                                   "profiles_per_launch"}
+    spec = json.loads(Path(benchmark_file).read_text())
+    assert set(out["metrics"]) == {
+        m["name"] for m in spec["per_layer"]
+        if "tiny-pelec.analyze" in m["workloads"]
+        and m["source"] != "device_trace"}
     assert "busy_s" not in out["device"]
 
 
