@@ -573,12 +573,13 @@ def phase1_unify_inprocess(profile_paths: list[str], timer: PhaseTimer,
         registry_jsons[i] = prof.environment.get("registry", [])
 
     ex.parallel_for(n, body)
+    timer.add("route_placeholders", float(sum(map(len, routes))))
     return unified, remaps, routes, identities, trace_lens, registry_jsons
 
 def transform_profile(prof: MeasurementProfile, remap_final, routes_final,
                       parent_pre: np.ndarray, end_arr: np.ndarray, *,
                       pipeline: str, keep_exclusive: bool, want_trace: bool,
-                      device=None):
+                      device=None, timer: PhaseTimer | None = None):
     """Phase-2 compute for one loaded profile: remap + redistribute +
     propagate (the paper's edit/redistribute/propagate chain) plus the
     per-profile statistics leaf.  Returns ``(sm, acc, trace_or_None)``.
@@ -588,12 +589,15 @@ def transform_profile(prof: MeasurementProfile, remap_final, routes_final,
     path — so the byte-determinism contract only has to be argued once.
     ``device`` is a :class:`repro.kernels.batch.DeviceAggregator` routing
     the combine/propagate hot loops through the Pallas kernels, or None for
-    the pure-numpy path.
+    the pure-numpy path.  ``timer`` receives the route split's span and
+    counters (:func:`repro.core.pipeline.fused_transform`); without one
+    they go to a throwaway timer.
     """
     remap_arr = np.asarray(remap_final, dtype=np.int64)
     sm = transform_plane(prof.metrics, remap_arr, routes_final, parent_pre,
                          end_arr, pipeline=pipeline,
-                         keep_exclusive=keep_exclusive, device=device)
+                         keep_exclusive=keep_exclusive, device=device,
+                         timer=timer)
     acc = StatsAccumulator()
     acc.update(sm)
     tr = (prof.trace.remap_contexts(remap_arr)
@@ -644,7 +648,8 @@ def phase2_stream_inprocess(profile_paths: list[str], remap_of, route_of,
                 sm, acc, tr = transform_profile(
                     prof, remap_of(i), route_of(i), parent_pre, end_arr,
                     pipeline=cfg.pipeline, keep_exclusive=cfg.keep_exclusive,
-                    want_trace=trace_sink is not None, device=device)
+                    want_trace=trace_sink is not None, device=device,
+                    timer=timer)
                 del prof
             with timer.span("phase2/encode"):
                 item = (sm.encode(), sm.n_contexts, sm.n_values, acc)

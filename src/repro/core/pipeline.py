@@ -48,6 +48,7 @@ from repro.core.propagate import (expand_routes, propagate_inclusive,
 from repro.core.sparse import (CTX_DTYPE, IDX_DTYPE, MID_DTYPE, VAL_DTYPE,
                                SparseMetrics)
 from repro.core.stats import check_key_ranges
+from repro.core.timer import PhaseTimer
 
 _KEY_SHIFT = 16
 
@@ -225,6 +226,7 @@ def transform_plane(
     pipeline: str = "fused",
     keep_exclusive: bool = True,
     device=None,
+    timer: PhaseTimer | None = None,
 ) -> SparseMetrics:
     """The one phase-2 transform dispatch, shared by every executor path
     (in-process bodies, sharded workers, the ranks driver).
@@ -234,10 +236,13 @@ def transform_plane(
     helper makes divergence structurally impossible.  ``device`` (a
     :class:`repro.kernels.batch.DeviceAggregator` or None) selects the
     ``compute="device"`` backend; it requires the fused pipeline.
+    ``timer`` (the analysis's :class:`repro.core.timer.PhaseTimer`)
+    receives the fused route split's span and counters.
     """
     if pipeline == "fused":
         return fused_transform(metrics, remap, routes, parent, end,
-                               keep_exclusive=keep_exclusive, device=device)
+                               keep_exclusive=keep_exclusive, device=device,
+                               timer=timer)
     if device is not None:
         raise ValueError("device compute requires pipeline='fused'")
     sm = metrics.remap_contexts(np.asarray(remap, dtype=np.int64))
@@ -256,6 +261,7 @@ def fused_transform(
     *,
     keep_exclusive: bool = True,
     device=None,
+    timer: PhaseTimer | None = None,
 ) -> SparseMetrics:
     """Remap + redistribute + propagate + assemble one profile's plane.
 
@@ -270,6 +276,12 @@ def fused_transform(
     dispatch to the Pallas kernels under that module's per-plane dtype
     contract; everything else — and the decision *what* to offload — is a
     pure function of the plane, preserving cross-executor byte parity.
+
+    The route split is the span ``phase2/routes`` of ``timer`` (a
+    :class:`repro.core.timer.PhaseTimer`; a throwaway one where the caller
+    keeps no timings), and its counters ``route_values_in`` (placeholder
+    entries after their combine) and ``route_values_out`` (the leaf entries
+    the split writes) grow by this plane's.
     """
     rows, mids, vals = metrics.triplets()
     if rows.size == 0:
@@ -281,14 +293,19 @@ def fused_transform(
     keys = rows * (1 << _KEY_SHIFT) + mids
 
     if routes:
-        ph_ids = np.fromiter(routes.keys(), dtype=np.int64)
-        is_ph = np.isin(rows, ph_ids)
-        # placeholder entries combine *before* weighting — (v1+v2)*w, the
-        # legacy order — then expand; everything else stays a raw stream
-        ph_keys, ph_vals = _combine_sorted(keys[is_ph], vals[is_ph])
-        r_keys, r_vals = _expand_route_keys(ph_keys, ph_vals, routes)
-        keys = np.concatenate([keys[~is_ph], r_keys])
-        vals = np.concatenate([vals[~is_ph], r_vals])
+        timer = timer or PhaseTimer()
+        with timer.span("phase2/routes"):
+            ph_ids = np.fromiter(routes.keys(), dtype=np.int64)
+            is_ph = np.isin(rows, ph_ids)
+            # placeholder entries combine *before* weighting — (v1+v2)*w,
+            # the legacy order — then expand; everything else stays a raw
+            # stream
+            ph_keys, ph_vals = _combine_sorted(keys[is_ph], vals[is_ph])
+            r_keys, r_vals = _expand_route_keys(ph_keys, ph_vals, routes)
+            keys = np.concatenate([keys[~is_ph], r_keys])
+            vals = np.concatenate([vals[~is_ph], r_vals])
+        timer.add("route_values_in", float(ph_keys.size))
+        timer.add("route_values_out", float(r_keys.size))
 
     # the one big argsort: raw remapped stream (+ route expansions) -> the
     # combined exclusive plane, sorted by (ctx, mid) key

@@ -2,6 +2,7 @@
 how much of phase 2 the leaf spans cover, the device transfer counters
 against the launches that were made, and the spans on a profiler trace's
 clock."""
+import contextlib
 import glob
 import threading
 
@@ -10,8 +11,12 @@ import pytest
 
 from repro.core import timer as timer_mod
 from repro.core.aggregate import AggregationConfig, StreamingAggregator
+from repro.core.cct import (KIND_LINE, KIND_LOOP, KIND_MODULE, KIND_OP,
+                            KIND_PHASE, KIND_ROUTE, ContextTree)
+from repro.core.lexical import StructureInfo
 from repro.core.metrics import INCLUSIVE_BIT
 from repro.core.pms import PMSReader
+from repro.core.sparse import MeasurementProfile, SparseMetrics
 from repro.core.timer import PhaseTimer
 from tests.conftest import make_profile
 
@@ -190,3 +195,176 @@ def test_spans_share_the_device_trace_clock(tmp_path, rng):
         assert any(p <= a and b <= q for p, q in device)
     for name in ("phase1", "phase2", "completion", "cms", "cms/gather"):
         assert intervals(name), name
+
+
+# ---------------------------------------------------------------------------
+# the route split (paper §4.1.3): its span and counters, on a fleet whose
+# binaries carry structure
+# ---------------------------------------------------------------------------
+
+def _structured_fleet(tmp_path, rng, *, exact=False, n=6, n_ops=40,
+                      scopes=2, routed_share=0.3, n_routes=3):
+    """A tiny PeleC(1+82)-shaped fleet with binary structure: CPU rank
+    profiles (metric 0) and GPU stream profiles (metrics 1-82) alternate.
+    Each binary's structure file names every op under ``scopes`` scopes;
+    ``routed_share`` of the GPU binary's ops have ``n_routes`` weighted
+    routes.  Each profile holds a random subset of the ops, with a line
+    below some of them, and values on ops and lines.  With ``exact``,
+    values are small integers and a routed op's weights are a permutation
+    of (1, 1, 2), so every split value and every sum is exact in float32.
+
+    Returns the paths and, per profile, ``(placeholders, entries)``: its
+    routed ops, and its distinct (routed op, metric) entries."""
+    ops = [f"op{i}" for i in range(n_ops)]
+    files, routed = {}, set()
+    for binary in ("cpu", "gpu"):
+        s = StructureInfo(binary)
+        if binary == "gpu":
+            routed = set(rng.choice(n_ops, int(n_ops * routed_share),
+                                    replace=False).tolist())
+        for i, op in enumerate(ops):
+            k = n_routes if binary == "gpu" and i in routed else 1
+            weights = (rng.permutation([1.0, 1.0, 2.0]) if exact and k == 3
+                       else rng.integers(1, 1001, k).astype(float))
+            for r in range(k):
+                path = [((KIND_MODULE, KIND_LOOP)[d % 2],
+                         f"{binary}.{'fl'[d % 2]}{(i + r + d) % 7}.r{r}")
+                        for d in range(scopes)]
+                s.add_op(op, path, weights[r])
+        files[binary] = str(tmp_path / f"{binary}.struct.json")
+        s.save(files[binary])
+
+    paths, expected = [], []
+    for p in range(n):
+        gpu = p % 2 == 1
+        t = ContextTree()
+        main = t.child(0, KIND_PHASE, "main")
+        mods = [t.child(main, KIND_MODULE, f"mod{j}") for j in range(4)]
+        held = sorted(rng.choice(n_ops, n_ops * 3 // 5, replace=False))
+        rows, mids = [], []
+        entries = 0
+        for i in held:
+            op = t.child(mods[i % 4], KIND_OP, ops[i])
+            line = t.child(op, KIND_LINE, f"line{i}") if i % 3 == 0 else None
+            pool = np.arange(1, 83) if gpu else np.array([0])
+            m = rng.choice(pool, min(2, pool.size), replace=False)
+            rows += [op] * m.size
+            mids += m.tolist()
+            if gpu and i in routed:
+                entries += m.size
+            if line is not None:
+                rows.append(line)
+                mids.append(int(m[0]))
+        vals = (rng.integers(1, 9, len(rows)).astype(float) if exact
+                else rng.exponential(1.0, len(rows)))
+        prof = MeasurementProfile(
+            identity={"rank": p // 2, "kind": "gpu" if gpu else "cpu"},
+            file_paths=[files["gpu" if gpu else "cpu"]], tree=t,
+            metrics=SparseMetrics.from_triplets(rows, mids, vals))
+        path = tmp_path / f"prof{p:03d}.rprf"
+        prof.save(path)
+        paths.append(str(path))
+        expected.append((sum(i in routed for i in held) if gpu else 0,
+                         entries))
+    return paths, expected
+
+
+def _config(compute, **kw):
+    return AggregationConfig(compute=compute,
+                             device_interpret=compute == "device", **kw)
+
+
+@pytest.mark.parametrize("compute", ["cpu", "device"])
+def test_route_counters_count_the_split(tmp_path, rng, compute):
+    """``route_placeholders`` counts each profile's placeholders; each
+    placeholder entry, combined, goes to every leaf of its op's routes."""
+    paths, expected = _structured_fleet(tmp_path, rng)
+    res = StreamingAggregator(tmp_path / "db", _config(
+        compute, executor="threads", n_workers=3)).run(paths)
+    t = res.timings
+    placeholders = sum(ph for ph, _ in expected)
+    entries = sum(e for _, e in expected)
+    assert placeholders > 0 and entries > 0
+    assert t["route_placeholders"] == placeholders
+    assert t["route_values_in"] == entries
+    assert t["route_values_out"] == 3 * entries   # three routes an op
+    assert t["phase2/routes"] > 0
+    # every placeholder of the unified tree is some profile's
+    with PMSReader(res.pms_path) as pms:
+        kinds = np.asarray(pms.tree.kind)
+    assert 0 < np.count_nonzero(kinds == KIND_ROUTE) <= placeholders
+
+
+@pytest.mark.parametrize("compute", ["cpu", "device"])
+def test_no_route_span_or_counts_without_structure(tmp_path, rng, compute):
+    paths = _fleet(tmp_path, rng, n=4, n_nodes=120)
+    t = StreamingAggregator(tmp_path / "db", _config(compute)).run(
+        paths).timings
+    assert "phase2/routes" not in t
+    assert t.get("route_values_in", 0) == t.get("route_values_out", 0) == 0
+    assert t.get("route_placeholders", 0) == 0
+
+
+def _db_bytes(res):
+    with open(res.pms_path, "rb") as a, open(res.cms_path, "rb") as b:
+        return a.read(), b.read()
+
+
+@pytest.mark.parametrize("compute", ["cpu", "device"])
+def test_route_span_leaves_the_database_as_it_was(tmp_path, rng, compute,
+                                                  monkeypatch):
+    """The databases of a structured fleet are the same bytes with every
+    span of the timer stubbed out."""
+    paths, _ = _structured_fleet(tmp_path, rng)
+    cfg = _config(compute, executor="threads", n_workers=3)
+    timed = _db_bytes(StreamingAggregator(tmp_path / "timed", cfg).run(paths))
+
+    @contextlib.contextmanager
+    def no_span(self, name, *, wall=False):
+        yield
+
+    monkeypatch.setattr(PhaseTimer, "span", no_span)
+    res = StreamingAggregator(tmp_path / "bare", cfg).run(paths)
+    assert "phase2/routes" not in res.timings
+    assert _db_bytes(res) == timed
+
+
+def test_structured_fleet_device_bytes_equal_cpu(tmp_path, rng):
+    """Integer values and dyadic route weights keep every split value and
+    sum exact in float32: the device path (interpret mode) writes the CPU
+    path's bytes, and both the legacy chain's (remap, then
+    ``redistribute_placeholders``, then ``propagate_inclusive``)."""
+    paths, _ = _structured_fleet(tmp_path, rng, exact=True)
+    dbs = {name: _db_bytes(StreamingAggregator(tmp_path / name, cfg).run(
+        paths)) for name, cfg in [
+            ("device", _config("device", executor="threads", n_workers=3)),
+            ("cpu", _config("cpu", executor="threads", n_workers=3)),
+            ("legacy", _config("cpu", pipeline="legacy"))]}
+    assert dbs["device"] == dbs["cpu"] == dbs["legacy"]
+
+
+def _planes(res):
+    with PMSReader(res.pms_path) as pms:
+        return [pms.plane(p) for p in range(pms.n_profiles)]
+
+
+@pytest.mark.parametrize("seed", [3, 4])
+def test_structured_fleet_agrees_with_the_legacy_chain(tmp_path, seed):
+    """Seeded exponential values: the fused CPU path writes the legacy
+    chain's bytes, and the device path (interpret mode) its values to
+    float32 precision."""
+    rng = np.random.default_rng(seed)
+    paths, _ = _structured_fleet(tmp_path, rng)
+    runs = {name: StreamingAggregator(tmp_path / name, cfg).run(paths)
+            for name, cfg in [("legacy", _config("cpu", pipeline="legacy")),
+                              ("cpu", _config("cpu")),
+                              ("device", _config("device"))]}
+    assert _db_bytes(runs["cpu"]) == _db_bytes(runs["legacy"])
+    for want, got in zip(_planes(runs["legacy"]), _planes(runs["device"])):
+        w = {(c, m): v for c, m, v in zip(*(a.tolist()
+                                            for a in want.triplets()))}
+        g = {(c, m): v for c, m, v in zip(*(a.tolist()
+                                            for a in got.triplets()))}
+        assert set(g) <= set(w)
+        for k, v in w.items():
+            assert g.get(k, 0.0) == pytest.approx(v, rel=1e-5, abs=1e-5), k
