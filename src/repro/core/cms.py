@@ -163,8 +163,11 @@ def _census_keys(pms: PMSReader) -> tuple[np.ndarray, np.ndarray]:
 # pass 2: per-group gather (two strategies)
 # ---------------------------------------------------------------------------
 
-def _gather_group_vectorized(pms: PMSReader, lo: int, hi: int) -> dict[int, bytes]:
-    """Transpose by sort: the TPU-shaped formulation (DESIGN.md §4)."""
+def _gather_group_vectorized(pms: PMSReader, lo: int, hi: int
+                             ) -> tuple[int, int, bytes] | None:
+    """Transpose by sort: the TPU-shaped formulation (DESIGN.md §4).
+    Returns the group's first context, its plane count and its planes'
+    bytes, or None for a group with no data."""
     rs, ms, ps, vs = [], [], [], []
     for pid in range(pms.n_profiles):
         sm = pms.plane(pid)
@@ -178,18 +181,65 @@ def _gather_group_vectorized(pms: PMSReader, lo: int, hi: int) -> dict[int, byte
         ms.append(sm.mid[i0:i1].astype(np.int64))
         ps.append(np.full(i1 - i0, pid, dtype=np.int64))
         vs.append(sm.val[i0:i1])
-    out: dict[int, bytes] = {}
     if not rs:
-        return out
+        return None
     rows = np.concatenate(rs); mids = np.concatenate(ms)
     pids = np.concatenate(ps); vals = np.concatenate(vs)
     order = np.lexsort((pids, mids, rows))
     rows, mids, pids, vals = rows[order], mids[order], pids[order], vals[order]
-    ctx_bounds = np.flatnonzero(np.diff(rows, prepend=-1))
-    ctx_ends = np.append(ctx_bounds[1:], rows.size)
-    for b, e in zip(ctx_bounds, ctx_ends):
-        out[int(rows[b])] = _encode_ctx_plane(mids[b:e], pids[b:e], vals[b:e])
-    return out
+    n_planes = int(np.count_nonzero(np.diff(rows))) + 1
+    return int(rows[0]), n_planes, _encode_group(rows, mids, pids, vals)
+
+
+# binio's dtype codes of a plane's blocks: mids, mstart, prof, vals
+_BLOCK_CODES = [np.frombuffer(c, np.uint8) for c in (b"u16 ", b"u64 ", b"u32 ", b"f64 ")]
+
+
+def _encode_group(rows, mids, pids, vals) -> bytes:
+    """A group's planes, one after another, from its entries sorted by
+    (ctx, mid, profile): the bytes of ``_encode_ctx_plane`` over each
+    context, built with array operations and no loop over contexts.
+
+    A plane's four blocks (mids u16[m], mstart u64[m+1], prof u32[x],
+    vals f64[x]) sit at fixed offsets from its start, so every header and
+    every value lands in one preallocated buffer by fancy indexing."""
+    n = rows.size
+    if n == 0:
+        return b""
+    new_ctx = np.diff(rows, prepend=-1) != 0
+    cb = np.flatnonzero(new_ctx)                       # context starts
+    mb = np.flatnonzero(new_ctx | (np.diff(mids, prepend=-1) != 0))  # runs
+    plane = np.cumsum(new_ctx) - 1                     # entry -> plane
+    run_plane = plane[mb]
+    x = np.diff(cb, append=n)
+    m = np.bincount(run_plane, minlength=cb.size)
+    size = 60 + 10 * m + 12 * x
+    poff = np.cumsum(size) - size
+    b0 = poff                                          # the four blocks
+    b1 = b0 + 13 + 2 * m
+    b2 = b1 + 13 + 8 * (m + 1)
+    b3 = b2 + 13 + 4 * x
+    buf = np.zeros(int(size.sum()), np.uint8)
+    # each block's 13-byte header: dtype code, ndim = 1, u64 length
+    head = np.empty((cb.size, 13), np.uint8)
+    head[:, 4] = 1
+    for start, code, length in zip((b0, b1, b2, b3), _BLOCK_CODES, (m, m + 1, x, x)):
+        head[:, :4] = code
+        head[:, 5:] = length.astype("<u8").view(np.uint8).reshape(-1, 8)
+        buf[start[:, None] + np.arange(13)] = head
+
+    def put(at, arr):
+        width = arr.dtype.itemsize
+        buf[at[:, None] + np.arange(width)] = arr.view(np.uint8).reshape(-1, width)
+
+    entry_rank = np.arange(n) - cb[plane]
+    run_rank = np.arange(mb.size) - (np.cumsum(m) - m)[run_plane]
+    put(b0[run_plane] + 13 + 2 * run_rank, mids[mb].astype(np.uint16))
+    put(b1[run_plane] + 13 + 8 * run_rank, (mb - cb[run_plane]).astype(np.uint64))
+    put(b1 + 13 + 8 * m, x.astype(np.uint64))          # the closing mstart
+    put(b2[plane] + 13 + 4 * entry_rank, pids.astype(np.uint32))
+    put(b3[plane] + 13 + 8 * entry_rank, vals.astype(np.float64))
+    return buf.tobytes()
 
 
 def _encode_ctx_plane(mids, pids, vals) -> bytes:
@@ -199,8 +249,10 @@ def _encode_ctx_plane(mids, pids, vals) -> bytes:
     return _encode_plane(umids, mstart, pids.astype(np.uint32), vals.astype(np.float64))
 
 
-def _gather_group_heap(pms: PMSReader, lo: int, hi: int) -> dict[int, bytes]:
-    """Faithful heap-merge over profiles (paper §4.3.2)."""
+def _gather_group_heap(pms: PMSReader, lo: int, hi: int
+                       ) -> tuple[int, int, bytes] | None:
+    """Faithful heap-merge over profiles (paper §4.3.2), one context at a
+    time; returns what :func:`_gather_group_vectorized` returns."""
     planes = []
     heap: list[tuple[int, int]] = []
     cursors = {}
@@ -242,7 +294,9 @@ def _gather_group_heap(pms: PMSReader, lo: int, hi: int) -> dict[int, bytes]:
         if k0 < k1:
             heapq.heappush(heap, (int(sm.ctx[k0]), pid))
     flush()
-    return out
+    if not out:
+        return None
+    return min(out), len(out), b"".join(out[c] for c in sorted(out))
 
 
 # ---------------------------------------------------------------------------
@@ -256,7 +310,7 @@ def _cms_shard_worker(task) -> int:
     written the header + offset table to the output file, so the worker
     re-reads them from there (the §4.3.2 property: once sizes are known,
     workers coordinate through precomputed offsets alone).  Returns the
-    number of planes written (progress/debug only).
+    number of planes written.
     """
     pms_path, out_path, strategy, groups = task
     pms = PMSReader(pms_path)
@@ -271,12 +325,12 @@ def _cms_shard_worker(task) -> int:
               else _gather_group_heap)
     written = 0
     for lo, hi in groups:
-        planes = gather(pms, lo, hi)
-        if not planes:
+        group = gather(pms, lo, hi)
+        if group is None:
             continue
-        buf = b"".join(planes[c] for c in sorted(planes))
-        os.pwrite(fd, buf, int(offsets[min(planes)]))
-        written += len(planes)
+        first, n_planes, buf = group
+        os.pwrite(fd, buf, int(offsets[first]))
+        written += n_planes
     f.close()
     pms.close()
     return written
@@ -316,7 +370,8 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
     file bytes never depend on the backend.  ``timer``, when given,
     receives the ``cms/*`` spans (census, census_device, scan, gather,
     write), the device transfer bytes and the number of device launches of
-    each (``device_census_launches``, ``device_scan_launches``).
+    each (``device_census_launches``, ``device_scan_launches``), and
+    ``cms_planes``, the planes the gather wrote.
     """
     timer = timer if timer is not None else PhaseTimer()
     with timer.span("cms/census"):
@@ -358,29 +413,27 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
         tasks = [(str(pms_path), str(out_path), strategy, shard)
                  for shard in _shard_groups(groups, sizes, n_workers)]
         with ex:
-            for _ in ex.map_unordered(_cms_shard_worker, tasks):
-                pass
+            for _, written in ex.map_unordered(_cms_shard_worker, tasks):
+                timer.add("cms_planes", written)
     else:
         assigner = loadbalance.make_assigner(balance, groups, sizes, n_workers)
 
         def worker(w: int):
-            # every worker opens its own reader: no shared file positions
-            with timer.span("cms/gather"):
-                wpms = PMSReader(pms_path)
+            # the workers share the reader: its reads are positional
             while True:
                 g = assigner.next_group(w)
                 if g is None:
                     break
                 lo, hi = g
                 with timer.span("cms/gather"):
-                    planes = gather(wpms, lo, hi)
-                if not planes:
+                    group = gather(pms, lo, hi)
+                if group is None:
                     continue
                 # group planes are contiguous: one buffer, one pwrite
+                first, n_planes, buf = group
+                timer.add("cms_planes", n_planes)
                 with timer.span("cms/write"):
-                    buf = b"".join(planes[c] for c in sorted(planes))
-                    os.pwrite(fd, buf, int(offsets[min(planes)]))
-            wpms.close()
+                    os.pwrite(fd, buf, int(offsets[first]))
 
         with ex:
             ex.parallel_for(n_workers, worker)

@@ -6,7 +6,9 @@ import pytest
 from tests._hypothesis_compat import given, settings, st
 
 from repro.core.cct import ContextTree
-from repro.core.cms import CMSReader, build_cms, census
+from repro.core import cms as cms_mod
+from repro.core.cms import (CMSReader, _encode_group, _gather_group_heap,
+                            _gather_group_vectorized, build_cms, census)
 from repro.core.dense_baseline import DenseAnalysis
 from repro.core.metrics import INCLUSIVE_BIT
 from repro.core.pms import PMSReader, PMSWriter
@@ -17,6 +19,7 @@ from repro.core.sparse import SparseMetrics
 from repro.core.stats import StatsAccumulator
 from repro.core.traces import TraceDBReader, TraceDBWriter
 from repro.core.sparse import Trace
+from repro.utils import binio
 from tests.conftest import make_profile, random_sparse, random_tree
 
 
@@ -244,6 +247,112 @@ def test_census_sizes_exact(tmp_path, rng):
         assert x_c[c] == len(pairs)
         assert m_c[c] == len({m for _, m in pairs})
     pms.close()
+
+
+def _reference_planes(planes, lo, hi) -> bytes:
+    """Each context of [lo, hi) with data, one ``binio.pack_array`` per
+    block: mids u16[m], mstart u64[m+1], prof u32[x], vals f64[x]."""
+    out = b""
+    for c in range(lo, hi):
+        cells = sorted((m, p, v) for p, sm in enumerate(planes)
+                       for m, v in zip(*sm.context_slice(c)))
+        if not cells:
+            continue
+        starts = {}
+        for i, (m, _, _) in enumerate(cells):
+            starts.setdefault(m, i)
+        umids = list(starts)
+        mstart = list(starts.values()) + [len(cells)]
+        out += (binio.pack_array(np.array(umids, np.uint16))
+                + binio.pack_array(np.array(mstart, np.uint64))
+                + binio.pack_array(np.array([p for _, p, _ in cells], np.uint32))
+                + binio.pack_array(np.array([v for _, _, v in cells], np.float64)))
+    return out
+
+
+def _planes(*triplets):
+    return [SparseMetrics.from_triplets(*(np.asarray(a) for a in t))
+            for t in triplets]
+
+
+_NO_ENTRIES = ([], [], [])
+
+# Each case: its profiles' planes (built from rng) and the group [lo, hi).
+_GROUP_CASES = {
+    "empty": lambda rng: (_planes(([5], [1], [1.0]), _NO_ENTRIES), 0, 5),
+    "one_value": lambda rng: (_planes(([3], [7], [2.5])), 0, 10),
+    "all_83_metrics": lambda rng: (_planes(
+        *(([4] * 83, np.arange(83), rng.uniform(1, 2, 83)) for _ in range(3))),
+        0, 8),
+    "mids_near_65535": lambda rng: (_planes(
+        ([1, 1, 2], [65533, 65535, 65534], [1.0, 2.0, 3.0]),
+        ([1, 2, 2], [65535, 0, 65535], [4.0, 5.0, 6.0])), 0, 3),
+    "profile_without_entries": lambda rng: (_planes(
+        ([0, 2], [1, 1], [1.0, 2.0]), ([40], [3], [9.0]),
+        ([2, 3], [0, 1], [3.0, 4.0])), 0, 10),
+    # one context whose plane is larger than the 1 MiB group target
+    "context_above_group_target": lambda rng: (_planes(
+        *(([6] * 2200, np.arange(2200), rng.exponential(1.0, 2200))
+          for _ in range(40))), 0, 10),
+    "first_context_after_lo": lambda rng: (_planes(
+        *((rng.integers(23, 60, 40), rng.integers(0, 8, 40),
+           rng.uniform(1, 2, 40)) for _ in range(6)),
+        ([5, 57], [1, 2], [1.0, 1.0])), 20, 50),
+}
+
+
+@pytest.mark.parametrize("case", list(_GROUP_CASES))
+def test_encode_group_matches_per_context_pack_array(tmp_path, rng, case):
+    """The group encoder writes, byte for byte, what one ``pack_array`` per
+    block of each context writes; so does the gather that feeds it."""
+    planes, lo, hi = _GROUP_CASES[case](rng)
+    w = PMSWriter(tmp_path / "db.pms", len(planes))
+    for pid, sm in enumerate(planes):
+        w.add_plane(pid, sm)
+    w.finalize()
+    want = _reference_planes(planes, lo, hi)
+    if case == "context_above_group_target":
+        assert len(want) > 1 << 20
+    rows, mids, pids, vals = (np.concatenate(a) for a in zip(*(
+        (*sm.triplets()[:2], np.full(sm.val.size, p), sm.val)
+        for p, sm in enumerate(planes))))
+    keep = (rows >= lo) & (rows < hi)
+    order = np.lexsort((pids[keep], mids[keep], rows[keep]))
+    got = _encode_group(*(a[keep][order] for a in (rows, mids, pids, vals)))
+    assert got == want
+    with PMSReader(tmp_path / "db.pms") as pms:
+        group = _gather_group_vectorized(pms, lo, hi)
+        heap = _gather_group_heap(pms, lo, hi)
+    if not want:
+        assert group is None and heap is None
+        return
+    first = min(c for sm in planes for c in sm.ctx.tolist() if lo <= c < hi)
+    n_planes = len({c for sm in planes for c in sm.ctx.tolist() if lo <= c < hi})
+    assert group == heap == (first, n_planes, want)
+    if case == "first_context_after_lo":
+        assert first > lo
+
+
+def test_vectorized_cms_encodes_no_plane_block_by_block(tmp_path, rng,
+                                                        monkeypatch):
+    """The vectorised build never calls ``binio.pack_array``: no context
+    is encoded on its own (the heap strategy, the control, does)."""
+    _, pms_path = _build_pms(tmp_path, rng)
+    calls = []
+    pack_array = binio.pack_array
+
+    def counted(arr):
+        calls.append(arr.size)
+        return pack_array(arr)
+
+    monkeypatch.setattr(cms_mod.binio, "pack_array", counted)
+    build_cms(pms_path, tmp_path / "v.cms", strategy="vectorized",
+              n_workers=2, group_target_bytes=512)
+    assert calls == []
+    build_cms(pms_path, tmp_path / "h.cms", strategy="heap", n_workers=2,
+              group_target_bytes=512)
+    assert calls
+    assert (tmp_path / "v.cms").read_bytes() == (tmp_path / "h.cms").read_bytes()
 
 
 # ---------------------------------------------------------------------------

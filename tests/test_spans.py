@@ -4,6 +4,7 @@ against the launches that were made, and the spans on a profiler trace's
 clock."""
 import contextlib
 import glob
+import hashlib
 import threading
 
 import numpy as np
@@ -11,6 +12,7 @@ import pytest
 
 from repro.core import timer as timer_mod
 from repro.core.aggregate import AggregationConfig, StreamingAggregator
+from repro.core.cms import census
 from repro.core.cct import (KIND_LINE, KIND_LOOP, KIND_MODULE, KIND_OP,
                             KIND_PHASE, KIND_ROUTE, ContextTree)
 from repro.core.lexical import StructureInfo
@@ -341,6 +343,25 @@ def test_structured_fleet_device_bytes_equal_cpu(tmp_path, rng):
             ("cpu", _config("cpu", executor="threads", n_workers=3)),
             ("legacy", _config("cpu", pipeline="legacy"))]}
     assert dbs["device"] == dbs["cpu"] == dbs["legacy"]
+
+
+@pytest.mark.parametrize("executor", ["serial", "threads", "processes"])
+def test_cms_digest_equals_the_heap_merge(tmp_path, rng, executor):
+    """On a structured fleet, every executor's vectorised CMS is the heap
+    merge's bytes, and ``cms_planes`` counts each context with data."""
+    paths, _ = _structured_fleet(tmp_path, rng)
+    runs = {strategy: StreamingAggregator(tmp_path / strategy, _config(
+        "cpu", executor=executor, n_workers=3, cms_workers=3,
+        group_target_bytes=2048, cms_strategy=strategy)).run(paths)
+        for strategy in ("vectorized", "heap")}
+    digests = {strategy: hashlib.sha256(open(res.cms_path, "rb").read()
+                                        ).hexdigest()
+               for strategy, res in runs.items()}
+    assert digests["vectorized"] == digests["heap"]
+    res = runs["vectorized"]
+    with PMSReader(res.pms_path) as pms:
+        x_c, _ = census(pms, res.n_contexts)
+    assert res.timings["cms_planes"] == np.count_nonzero(x_c) > 0
 
 
 def _planes(res):
