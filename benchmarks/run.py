@@ -26,9 +26,9 @@ def main() -> None:
                     help="CI-sized workloads for suites that support it")
     args = ap.parse_args()
 
-    from benchmarks import (agg_throughput, fig6_breakdown, kernels_bench,
-                            query_latency, serve_load, table1_measurement,
-                            table2_analysis, table4_agg_time, table5_glb)
+    from benchmarks import (fig6_breakdown, kernels_bench, query_latency,
+                            serve_load, table1_measurement, table2_analysis,
+                            table4_agg_time, table5_glb)
     suites = {
         "table1": table1_measurement.run,
         "table2": table2_analysis.run,
@@ -37,7 +37,6 @@ def main() -> None:
         "fig6": fig6_breakdown.run,
         "query": query_latency.run,
         "kernels": kernels_bench.run,
-        "agg": agg_throughput.run,
         "serve": serve_load.run,
     }
     print("name,us_per_call,derived")

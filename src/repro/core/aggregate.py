@@ -28,8 +28,10 @@ Execution substrate — the :mod:`repro.runtime` backends (paper §4.2 / §4.4):
   single writer feeding :class:`TwoBufferWriter`.
 
 **Determinism contract:** all three backends produce byte-identical PMS and
-CMS databases for the same inputs and config.  Three mechanisms pin this
-down: (1) ``ContextTree.preorder`` orders children canonically so final
+CMS databases for the same inputs and output options (``keep_exclusive``,
+``write_cms``, ``write_traces``); every step has one implementation, and
+no other option selects between two.  Three mechanisms pin this down:
+(1) ``ContextTree.preorder`` orders children canonically so final
 context ids are a function of tree *content*, not insertion schedule;
 (2) plane appends pass through :class:`repro.runtime.OrderedSink`, pinning
 region allocation to profile order; (3) summary statistics are accumulated
@@ -50,7 +52,7 @@ import numpy as np
 from repro.core import cms as cms_mod
 from repro.core.cct import ContextTree
 from repro.core.lexical import StructureInfo, expand_profile_tree
-from repro.core.pipeline import transform_plane
+from repro.core.pipeline import fused_transform
 from repro.core.pms import PMSWriter
 from repro.core.sparse import MeasurementProfile, Trace
 from repro.core.stats import StatsAccumulator
@@ -73,18 +75,11 @@ class AggregationConfig:
                                          # in-process backends; None = auto
                                          # (2 x workers), 0 = unbounded
     cms_workers: int = 4
-    cms_strategy: str = "vectorized"     # or "heap" (paper-faithful merge)
     cms_balance: str = "dynamic"         # GLB (paper §4.4) or "static"
     group_target_bytes: int = 1 << 20
     write_cms: bool = True
     write_traces: bool = True
     keep_exclusive: bool = True
-    pipeline: str = "fused"              # fused single-sort phase-2 kernel,
-                                         # or "legacy" (three-pass chain);
-                                         # byte-identical outputs either way
-    plane_transport: str = "shm"         # processes backend: "shm" slab
-                                         # arena or "pickle" through the
-                                         # pool pipe; byte-identical outputs
     shm_slab_bytes: int = 1 << 20        # slab size; bigger planes fall
                                          # back to one-shot segments
     compute: str = "cpu"                 # "cpu" numpy hot loops, or "device"
@@ -97,11 +92,6 @@ class AggregationConfig:
                                          # kernels in interpret mode on the
                                          # CPU backend (tests; slow, but
                                          # exercises the real kernel bodies)
-    stats_merge: str = "auto"            # cross-profile stats carry-chain:
-                                         # "inline" on the consume thread,
-                                         # "workers" on a small merge pool
-                                         # (byte-identical fold shape), or
-                                         # "auto" = workers iff workers > 1
 
     @property
     def workers(self) -> int:
@@ -116,12 +106,8 @@ class AggregationConfig:
 
     def _check_device(self) -> None:
         """``compute="device"`` runs where it was asked to or not at all:
-        on the fused pipeline, in the one process that holds the
-        accelerator, and on the CPU backend only with the explicit
-        ``device_interpret`` opt-in."""
-        if self.pipeline == "legacy":
-            raise ValueError("compute='device' requires pipeline='fused'; "
-                             "the legacy three-pass chain has no device path")
+        in the one process that holds the accelerator, and on the CPU
+        backend only with the explicit ``device_interpret`` opt-in."""
         if self.executor in ("processes", "ranks"):
             raise ValueError(
                 f"compute='device' cannot run under executor="
@@ -137,11 +123,6 @@ class AggregationConfig:
                 f"with a TPU, pass device_interpret=True "
                 f"(--device-interpret) to run the kernels in interpret mode "
                 f"on the CPU, or use compute='cpu'")
-
-    def resolved_stats_merge(self) -> str:
-        if self.stats_merge != "auto":
-            return self.stats_merge
-        return "workers" if self.workers > 1 else "inline"
 
     @property
     def effective_sink_window(self) -> int | None:
@@ -265,11 +246,11 @@ def _merge_stats(a: StatsAccumulator, b: StatsAccumulator) -> StatsAccumulator:
 
 
 def _make_stats_reducer(cfg: AggregationConfig):
-    """The cross-profile statistics fold: same carry-chain shape either way
-    (byte-identical results), ``"workers"`` just runs the merges on a small
-    pool instead of the consume thread (ROADMAP item 3 — the sharded path's
-    parent-side merge bottleneck)."""
-    if cfg.resolved_stats_merge() == "workers":
+    """The cross-profile statistics fold: the same carry-chain shape either
+    way (byte-identical results).  With more than one worker the merges run
+    on a small pool instead of the consume thread, which would otherwise
+    serialize behind them while the workers wait."""
+    if cfg.workers > 1:
         return AsyncStreamingReducer(_merge_stats, n_threads=2)
     return StreamingReducer(_merge_stats)
 
@@ -296,16 +277,6 @@ class StreamingAggregator:
 
     # -- full run --------------------------------------------------------------
     def run(self, profile_paths: list[str]) -> AnalysisResult:
-        if self.cfg.pipeline not in ("fused", "legacy"):
-            raise ValueError(f"unknown pipeline {self.cfg.pipeline!r}; "
-                             f"expected 'fused' or 'legacy'")
-        if self.cfg.plane_transport not in ("shm", "pickle"):
-            raise ValueError(f"unknown plane_transport "
-                             f"{self.cfg.plane_transport!r}; expected 'shm' "
-                             f"or 'pickle'")
-        if self.cfg.stats_merge not in ("auto", "inline", "workers"):
-            raise ValueError(f"unknown stats_merge {self.cfg.stats_merge!r}; "
-                             f"expected 'auto', 'inline' or 'workers'")
         with get_executor(self.cfg.executor, self.cfg.workers) as ex:
             if ex.driver == "ranks":
                 # whole-run driver backend (paper §4.4): n_workers ranks,
@@ -502,7 +473,7 @@ class StreamingAggregator:
                 with timer.span("cms", wall=True):
                     cms_bytes = cms_mod.build_cms(
                         pms.path, cms_path, n_workers=cfg.cms_workers,
-                        strategy=cfg.cms_strategy, balance=cfg.cms_balance,
+                        balance=cfg.cms_balance,
                         group_target_bytes=cfg.group_target_bytes,
                         executor=cfg.executor, timer=timer,
                         compute=cfg.compute)
@@ -578,7 +549,7 @@ def phase1_unify_inprocess(profile_paths: list[str], timer: PhaseTimer,
 
 def transform_profile(prof: MeasurementProfile, remap_final, routes_final,
                       parent_pre: np.ndarray, end_arr: np.ndarray, *,
-                      pipeline: str, keep_exclusive: bool, want_trace: bool,
+                      keep_exclusive: bool, want_trace: bool,
                       device=None, timer: PhaseTimer | None = None):
     """Phase-2 compute for one loaded profile: remap + redistribute +
     propagate (the paper's edit/redistribute/propagate chain) plus the
@@ -594,10 +565,9 @@ def transform_profile(prof: MeasurementProfile, remap_final, routes_final,
     they go to a throwaway timer.
     """
     remap_arr = np.asarray(remap_final, dtype=np.int64)
-    sm = transform_plane(prof.metrics, remap_arr, routes_final, parent_pre,
-                         end_arr, pipeline=pipeline,
-                         keep_exclusive=keep_exclusive, device=device,
-                         timer=timer)
+    sm = fused_transform(prof.metrics, remap_arr, routes_final, parent_pre,
+                         end_arr, keep_exclusive=keep_exclusive,
+                         device=device, timer=timer)
     acc = StatsAccumulator()
     acc.update(sm)
     tr = (prof.trace.remap_contexts(remap_arr)
@@ -647,7 +617,7 @@ def phase2_stream_inprocess(profile_paths: list[str], remap_of, route_of,
             with timer.span("phase2/transform"):
                 sm, acc, tr = transform_profile(
                     prof, remap_of(i), route_of(i), parent_pre, end_arr,
-                    pipeline=cfg.pipeline, keep_exclusive=cfg.keep_exclusive,
+                    keep_exclusive=cfg.keep_exclusive,
                     want_trace=trace_sink is not None, device=device,
                     timer=timer)
                 del prof
@@ -682,8 +652,10 @@ def phase2_stream_sharded(profile_paths: list[str], remaps_final,
                           parent_pre: np.ndarray, end_arr: np.ndarray,
                           timer: PhaseTimer, consume, trace_sink=None):
     """Phase-2 streaming over a ``processes`` executor with pluggable
-    output hooks: propagate/encode runs in pool workers (shm slab arena or
-    pickle transport), then ``consume(i, payload, n_ctx, n_vals, acc)``
+    output hooks: propagate/encode runs in pool workers, which ship each
+    result through a shared-memory slab when the host can make a
+    :class:`repro.runtime.shm.SlabArena`, else inline in the result
+    descriptor; then ``consume(i, payload, n_ctx, n_vals, acc)``
     and ``trace_sink(i, trace)`` run in profile order on the consuming
     thread.  ``payload`` and the trace arrays may be views into a shm slab
     that is recycled when the hook returns — hooks must copy anything they
@@ -691,24 +663,23 @@ def phase2_stream_sharded(profile_paths: list[str], remaps_final,
     into its resident planes).
 
     Submission credits bound in-flight profiles (worker-resident or
-    buffered out of order in the sink) to the sink window; with the shm
-    transport the window doubles as the slab count, so slab recycling *is*
-    the submission throttle and the single-producer feed below can never
+    buffered out of order in the sink) to the sink window; with slabs the
+    window doubles as the slab count, so slab recycling *is* the
+    submission throttle and the single-producer feed below can never
     block on its own bounded sink (the next-expected profile is always
     already submitted).  An explicit ``sink_window=0`` ("unbounded") stays
-    unthrottled on the pickle transport, where no slab scarcity requires a
+    unthrottled on the inline path, where no slab scarcity requires a
     bound.
     """
     n = len(profile_paths)
     window = cfg.effective_sink_window
     n_slabs = window if window is not None else max(2 * cfg.workers, 2)
     arena = None
-    transport = cfg.plane_transport
-    if transport == "shm" and n > 0:
+    if n > 0:
         try:
             arena = shm_mod.SlabArena(n_slabs, cfg.shm_slab_bytes)
         except Exception:
-            transport = "pickle"  # no usable /dev/shm: fall back
+            pass  # no usable /dev/shm: results ride inline
     n_credits = (window if window is not None
                  else n_slabs if arena is not None else None)
 
@@ -734,11 +705,11 @@ def phase2_stream_sharded(profile_paths: list[str], remaps_final,
 
     sink = OrderedSink(_consume, window=window)
     initargs = (end_arr, parent_pre, cfg.keep_exclusive, cfg.write_traces,
-                cfg.pipeline, cfg.shm_slab_bytes)
+                cfg.shm_slab_bytes)
 
     def task_source():
-        # pulled lazily by map_throttled, one task per credit: with the
-        # shm transport a free slab is guaranteed at every pull
+        # pulled lazily by map_throttled, one task per credit: with an
+        # arena a free slab is guaranteed at every pull
         for i in range(n):
             slab = arena.acquire() if arena is not None else None
             yield (profile_paths[i], remaps_final[i], routes_final[i], slab)
@@ -795,13 +766,13 @@ _STAT_FIELDS = ("keys", "sum", "cnt", "vmin", "vmax", "sumsq")
 
 
 def _phase2_init(end: np.ndarray, parent: np.ndarray, keep_exclusive: bool,
-                 write_traces: bool, pipeline: str, slab_bytes: int) -> None:
+                 write_traces: bool, slab_bytes: int) -> None:
     """Pool initializer: ship the (large) preorder-interval arrays once per
     worker instead of once per profile task."""
     global _PHASE2_STATE
     _PHASE2_STATE = (np.asarray(end, dtype=np.int64),
                      np.asarray(parent, dtype=np.int64),
-                     bool(keep_exclusive), bool(write_traces), pipeline,
+                     bool(keep_exclusive), bool(write_traces),
                      int(slab_bytes))
 
 
@@ -822,12 +793,10 @@ def _phase2_profile_worker(task) -> tuple:
     """
     path, remap_final, routes_final, slab_name = task
     assert _PHASE2_STATE is not None, "phase-2 worker used without initializer"
-    (end, parent, keep_exclusive, write_traces, pipeline,
-     slab_bytes) = _PHASE2_STATE
+    end, parent, keep_exclusive, write_traces, slab_bytes = _PHASE2_STATE
     prof = MeasurementProfile.load(path)
     sm, acc, tr = transform_profile(prof, remap_final, routes_final, parent,
-                                    end, pipeline=pipeline,
-                                    keep_exclusive=keep_exclusive,
+                                    end, keep_exclusive=keep_exclusive,
                                     want_trace=write_traces)
     if tr is not None:
         ttime, tctx = tr.time, tr.ctx

@@ -12,9 +12,10 @@ a single contiguous read, which is the access pattern CMS exists to serve.
 The builder follows paper §4.3.2: CMS is generated *from the completed PMS
 file*; sizes are known, so offsets come from an exclusive scan, and workers
 each assemble contiguous context groups and write at precomputed offsets
-without coordination.  Both the faithful **heap-merge** per-group gather and
-the TPU-shaped **vectorized transpose** (sort by (ctx, mid, profile)) are
-implemented; they produce byte-identical planes.
+without coordination.  The builder gathers each group with the TPU-shaped
+**vectorized transpose** (sort by (ctx, mid, profile)); the faithful
+**heap-merge** gather of the paper stays beside it as the reference the
+tests compare it with, byte for byte.
 """
 from __future__ import annotations
 
@@ -160,7 +161,7 @@ def _census_keys(pms: PMSReader) -> tuple[np.ndarray, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
-# pass 2: per-group gather (two strategies)
+# pass 2: per-group gather (and its reference)
 # ---------------------------------------------------------------------------
 
 def _gather_group_vectorized(pms: PMSReader, lo: int, hi: int
@@ -252,7 +253,9 @@ def _encode_ctx_plane(mids, pids, vals) -> bytes:
 def _gather_group_heap(pms: PMSReader, lo: int, hi: int
                        ) -> tuple[int, int, bytes] | None:
     """Faithful heap-merge over profiles (paper §4.3.2), one context at a
-    time; returns what :func:`_gather_group_vectorized` returns."""
+    time; returns what :func:`_gather_group_vectorized` returns.  The
+    builder never runs it: it is the reference that the tests substitute
+    for ``_gather_group_vectorized`` to check the build byte for byte."""
     planes = []
     heap: list[tuple[int, int]] = []
     cursors = {}
@@ -312,7 +315,7 @@ def _cms_shard_worker(task) -> int:
     workers coordinate through precomputed offsets alone).  Returns the
     number of planes written.
     """
-    pms_path, out_path, strategy, groups = task
+    pms_path, out_path, groups = task
     pms = PMSReader(pms_path)
     f = open(str(out_path), "r+b")
     fd = f.fileno()
@@ -321,11 +324,9 @@ def _cms_shard_worker(task) -> int:
     (n_ctx,) = struct.unpack_from("<Q", head, 8)
     raw = os.pread(fd, 8 * (int(n_ctx) + 1), _HEADER)
     offsets = np.frombuffer(raw, dtype=np.uint64)
-    gather = (_gather_group_vectorized if strategy == "vectorized"
-              else _gather_group_heap)
     written = 0
     for lo, hi in groups:
-        group = gather(pms, lo, hi)
+        group = _gather_group_vectorized(pms, lo, hi)
         if group is None:
             continue
         first, n_planes, buf = group
@@ -351,7 +352,7 @@ def _shard_groups(groups, sizes: np.ndarray, n_workers: int):
     return [s for s in shards if s]
 
 
-def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vectorized",
+def build_cms(pms_path, out_path, *, n_workers: int = 4,
               balance: str = "dynamic", group_target_bytes: int = 1 << 20,
               executor: str | None = None, timer: PhaseTimer | None = None,
               compute: str = "cpu") -> int:
@@ -396,7 +397,6 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
         data_start = _HEADER + 8 * (n_ctx + 1)
         offsets += np.uint64(data_start)
         groups = loadbalance.make_groups(sizes, group_target_bytes)
-    gather = _gather_group_vectorized if strategy == "vectorized" else _gather_group_heap
 
     from repro.runtime import get_executor
     ex = get_executor(executor or "threads", n_workers)
@@ -410,7 +410,7 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
         f.flush()  # workers use positional pwrites from here on
 
     if not ex.in_process:
-        tasks = [(str(pms_path), str(out_path), strategy, shard)
+        tasks = [(str(pms_path), str(out_path), shard)
                  for shard in _shard_groups(groups, sizes, n_workers)]
         with ex:
             for _, written in ex.map_unordered(_cms_shard_worker, tasks):
@@ -426,7 +426,7 @@ def build_cms(pms_path, out_path, *, n_workers: int = 4, strategy: str = "vector
                     break
                 lo, hi = g
                 with timer.span("cms/gather"):
-                    group = gather(pms, lo, hi)
+                    group = _gather_group_vectorized(pms, lo, hi)
                 if group is None:
                     continue
                 # group planes are contiguous: one buffer, one pwrite

@@ -1,7 +1,10 @@
 """Fused phase-2 kernel: remap + redistribute + propagate + assemble in one
 pass over a single sorted triplet buffer.
 
-The legacy hot path re-materializes every profile three times::
+This is the phase-2 transform of every executor path (in-process bodies,
+sharded workers, the ranks driver).  The three-pass reference it is tested
+against, :mod:`repro.core.propagate`, re-materializes every profile three
+times::
 
     remap_contexts          -> from_triplets  (argsort #1)
     redistribute_placeholders -> from_triplets  (argsort #2)
@@ -43,8 +46,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core.metrics import INCLUSIVE_BIT
-from repro.core.propagate import (expand_routes, propagate_inclusive,
-                                  redistribute_placeholders)
+from repro.core.propagate import expand_routes
 from repro.core.sparse import (CTX_DTYPE, IDX_DTYPE, MID_DTYPE, VAL_DTYPE,
                                SparseMetrics)
 from repro.core.stats import check_key_ranges
@@ -214,42 +216,6 @@ def _assemble(keys: np.ndarray, vals: np.ndarray) -> SparseMetrics:
         ctx[bounds].astype(CTX_DTYPE), starts,
         (keys & 0xFFFF).astype(MID_DTYPE), vals.astype(VAL_DTYPE, copy=False),
     )
-
-
-def transform_plane(
-    metrics: SparseMetrics,
-    remap: np.ndarray,
-    routes: dict,
-    parent: np.ndarray,
-    end: np.ndarray,
-    *,
-    pipeline: str = "fused",
-    keep_exclusive: bool = True,
-    device=None,
-    timer: PhaseTimer | None = None,
-) -> SparseMetrics:
-    """The one phase-2 transform dispatch, shared by every executor path
-    (in-process bodies, sharded workers, the ranks driver).
-
-    The cross-executor byte-parity contract requires all paths to run the
-    exact same transform for a given config — routing them through this
-    helper makes divergence structurally impossible.  ``device`` (a
-    :class:`repro.kernels.batch.DeviceAggregator` or None) selects the
-    ``compute="device"`` backend; it requires the fused pipeline.
-    ``timer`` (the analysis's :class:`repro.core.timer.PhaseTimer`)
-    receives the fused route split's span and counters.
-    """
-    if pipeline == "fused":
-        return fused_transform(metrics, remap, routes, parent, end,
-                               keep_exclusive=keep_exclusive, device=device,
-                               timer=timer)
-    if device is not None:
-        raise ValueError("device compute requires pipeline='fused'")
-    sm = metrics.remap_contexts(np.asarray(remap, dtype=np.int64))
-    if routes:
-        sm = redistribute_placeholders(sm, routes)
-    return propagate_inclusive(sm, np.arange(end.size), end,
-                               keep_exclusive=keep_exclusive)
 
 
 def fused_transform(

@@ -11,8 +11,11 @@ so for a dense preorder value vector ``v``::
 
 One streaming pass instead of a recursive walk; batched over the (few)
 metrics a profile actually observed.  The Pallas ``blockscan`` kernel is the
-TPU implementation of the cumsum; this module is the numpy engine used by
-the post-mortem analysis tool.
+TPU implementation of the cumsum.  The analysis engine runs
+:func:`repro.core.pipeline.fused_transform`; the three-pass chain here
+(``remap_contexts``, :func:`redistribute_placeholders`,
+:func:`propagate_inclusive`) is the reference it is tested against byte for
+byte, and :func:`expand_routes` is the route expansion both share.
 """
 from __future__ import annotations
 
@@ -29,7 +32,9 @@ def propagate_inclusive(
     *,
     keep_exclusive: bool = True,
 ) -> SparseMetrics:
-    """Exclusive -> exclusive+inclusive for one profile.
+    """Exclusive -> exclusive+inclusive for one profile: the third pass of
+    the reference chain that :func:`repro.core.pipeline.fused_transform` is
+    tested against (and the dense baseline's propagation).
 
     ``pos``/``end`` come from ``ContextTree.preorder()`` of the *unified*
     tree; ``metrics`` must already be remapped onto unified context ids.
@@ -95,7 +100,9 @@ def redistribute_placeholders(
     metrics: SparseMetrics,
     routes: dict[int, tuple[np.ndarray, np.ndarray]],
 ) -> SparseMetrics:
-    """GPU-context-reconstruction redistribution (paper §4.1.3).
+    """GPU-context-reconstruction redistribution (paper §4.1.3): the second
+    pass of the reference chain that
+    :func:`repro.core.pipeline.fused_transform` is tested against.
 
     ``routes`` maps a placeholder context id ("in superposition") to
     ``(leaf_ctx_ids, weights)``; the placeholder's costs are split across the

@@ -26,7 +26,7 @@ from repro.core import cms as cms_mod
 from repro.core.aggregate import (AggregationConfig, AnalysisResult,
                                   StreamingAggregator, _renumber)
 from repro.core.cct import ContextTree
-from repro.core.pipeline import transform_plane
+from repro.core.pipeline import fused_transform
 from repro.core.pms import PMSWriter
 from repro.core.sparse import MeasurementProfile
 from repro.core.stats import StatsAccumulator
@@ -64,7 +64,7 @@ def _phase1_worker(args):
 
 def _phase2_worker(args):
     (rank, paths, remaps_final, routes_final, seg_path, trc_path,
-     end_arr, parent_arr, keep_exclusive, pipeline) = args
+     end_arr, parent_arr, keep_exclusive) = args
     acc = StatsAccumulator()
     records = []
     trace_blobs = []
@@ -72,9 +72,8 @@ def _phase2_worker(args):
         off = 0
         for i, path in enumerate(paths):
             prof = MeasurementProfile.load(path)
-            sm = transform_plane(prof.metrics, remaps_final[i],
+            sm = fused_transform(prof.metrics, remaps_final[i],
                                  routes_final[i], parent_arr, end_arr,
-                                 pipeline=pipeline,
                                  keep_exclusive=keep_exclusive)
             acc.update(sm)
             payload = sm.encode()
@@ -143,7 +142,7 @@ def aggregate_multiprocess(
             seg_path = os.path.join(out_dir, f"seg{r}.bin")
             phase2_args.append((r, shards[r], remaps_final, routes_final,
                                 seg_path, None, end, parent_pre,
-                                cfg.keep_exclusive, cfg.pipeline))
+                                cfg.keep_exclusive))
 
         # ---- phase 2: stream metrics per rank ----
         results2 = pool.map(_phase2_worker, phase2_args)
@@ -191,7 +190,6 @@ def aggregate_multiprocess(
         cms_path = os.path.join(out_dir, "db.cms")
         cms_bytes = cms_mod.build_cms(pms_path, cms_path,
                                       n_workers=cfg.cms_workers,
-                                      strategy=cfg.cms_strategy,
                                       balance=cfg.cms_balance,
                                       group_target_bytes=cfg.group_target_bytes)
 

@@ -308,8 +308,7 @@ class IngestState:
         if cfg.write_cms:
             cms_bytes = cms_mod.build_cms(
                 pms_path, os.path.join(out_dir, "db.cms"),
-                n_workers=cfg.cms_workers, strategy=cfg.cms_strategy,
-                balance=cfg.cms_balance,
+                n_workers=cfg.cms_workers, balance=cfg.cms_balance,
                 group_target_bytes=cfg.group_target_bytes,
                 executor=cfg.executor)
         return {"n_profiles": n, "n_contexts": len(final_tree),

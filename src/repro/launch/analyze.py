@@ -4,7 +4,7 @@ Aggregate profiles into the PMS/CMS/trace databases::
 
     PYTHONPATH=src python -m repro.launch.analyze runs/profiles/*.rprf \
         --out runs/db --executor processes --workers 4 \
-        [--heap] [--static-lb]
+        [--static-lb]
 
 Query a completed database (``repro.query`` front end)::
 
@@ -54,8 +54,6 @@ def _aggregate_main(argv):
     ap.add_argument("--sink-window", type=int, default=None,
                     help="ordered-sink out-of-order plane bound "
                          "(default: 2 x workers; 0 = unbounded)")
-    ap.add_argument("--heap", action="store_true",
-                    help="paper-faithful heap-merge CMS gather")
     ap.add_argument("--static-lb", action="store_true",
                     help="static context groups instead of GLB")
     ap.add_argument("--no-cms", action="store_true")
@@ -84,7 +82,6 @@ def _aggregate_main(argv):
         executor=executor,
         n_workers=workers,
         sink_window=args.sink_window,
-        cms_strategy="heap" if args.heap else "vectorized",
         cms_balance="static" if args.static_lb else "dynamic",
         write_cms=not args.no_cms,
         write_traces=not args.no_traces,

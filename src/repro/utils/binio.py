@@ -76,8 +76,8 @@ def pack_array_into(view, off: int, arr: np.ndarray) -> int:
     """Write the :func:`pack_array` layout directly into a writable buffer
     (a bytearray or shared-memory view) at ``off``; returns the new offset.
 
-    Byte-for-byte identical to ``pack_array`` — the slab transport and the
-    pickle transport must produce the same plane payloads.
+    Byte-for-byte identical to ``pack_array`` — a plane written into a
+    shared-memory slab must be the bytes of the same plane shipped inline.
     """
     arr = np.ascontiguousarray(arr)
     code = _CODE_FOR_DTYPE[arr.dtype]

@@ -201,13 +201,14 @@ def _build_pms(tmp_path, rng, P=8, n_ctx=60, n_met=8, density=0.15):
     return planes, tmp_path / "db.pms"
 
 
-@pytest.mark.parametrize("strategy", ["vectorized", "heap"])
-@pytest.mark.parametrize("balance", ["dynamic", "static"])
-def test_cms_matches_pms(tmp_path, rng, strategy, balance):
+@pytest.mark.parametrize("balance,executor", [
+    ("dynamic", "serial"), ("dynamic", "threads"), ("static", "threads"),
+    ("dynamic", "processes")])   # processes always splits groups statically
+def test_cms_matches_pms(tmp_path, rng, balance, executor):
     planes, pms_path = _build_pms(tmp_path, rng)
-    cms_path = tmp_path / f"db.{strategy}.{balance}.cms"
-    build_cms(pms_path, cms_path, n_workers=3, strategy=strategy,
-              balance=balance, group_target_bytes=512)
+    cms_path = tmp_path / f"db.{balance}.{executor}.cms"
+    build_cms(pms_path, cms_path, n_workers=3, balance=balance,
+              executor=executor, group_target_bytes=512)
     with CMSReader(cms_path) as r:
         for pid, sm in enumerate(planes):
             rows, mids, vals = sm.triplets()
@@ -215,10 +216,13 @@ def test_cms_matches_pms(tmp_path, rng, strategy, balance):
                 assert r.query(int(c), int(m), pid) == pytest.approx(v)
 
 
-def test_cms_strategies_byte_identical(tmp_path, rng):
+def test_cms_strategies_byte_identical(tmp_path, rng, monkeypatch):
+    """The vectorised build writes the heap merge's (paper §4.3.2) bytes."""
     _, pms_path = _build_pms(tmp_path, rng)
-    build_cms(pms_path, tmp_path / "a.cms", strategy="vectorized", n_workers=2)
-    build_cms(pms_path, tmp_path / "b.cms", strategy="heap", n_workers=2)
+    build_cms(pms_path, tmp_path / "a.cms", n_workers=2)
+    monkeypatch.setattr(cms_mod, "_gather_group_vectorized",
+                        _gather_group_heap)
+    build_cms(pms_path, tmp_path / "b.cms", n_workers=2)
     assert (tmp_path / "a.cms").read_bytes() == (tmp_path / "b.cms").read_bytes()
 
 
@@ -336,7 +340,7 @@ def test_encode_group_matches_per_context_pack_array(tmp_path, rng, case):
 def test_vectorized_cms_encodes_no_plane_block_by_block(tmp_path, rng,
                                                         monkeypatch):
     """The vectorised build never calls ``binio.pack_array``: no context
-    is encoded on its own (the heap strategy, the control, does)."""
+    is encoded on its own (the heap merge, the control, does)."""
     _, pms_path = _build_pms(tmp_path, rng)
     calls = []
     pack_array = binio.pack_array
@@ -346,10 +350,12 @@ def test_vectorized_cms_encodes_no_plane_block_by_block(tmp_path, rng,
         return pack_array(arr)
 
     monkeypatch.setattr(cms_mod.binio, "pack_array", counted)
-    build_cms(pms_path, tmp_path / "v.cms", strategy="vectorized",
-              n_workers=2, group_target_bytes=512)
+    build_cms(pms_path, tmp_path / "v.cms", n_workers=2,
+              group_target_bytes=512)
     assert calls == []
-    build_cms(pms_path, tmp_path / "h.cms", strategy="heap", n_workers=2,
+    monkeypatch.setattr(cms_mod, "_gather_group_vectorized",
+                        _gather_group_heap)
+    build_cms(pms_path, tmp_path / "h.cms", n_workers=2,
               group_target_bytes=512)
     assert calls
     assert (tmp_path / "v.cms").read_bytes() == (tmp_path / "h.cms").read_bytes()

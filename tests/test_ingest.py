@@ -418,12 +418,17 @@ def test_spool_checksum_quarantines_corrupt_entries(tmp_path):
     # ...and legacy junk that never was a profile
     open(os.path.join(spool, "000000000099.rprf"), "wb").write(b"not rprf")
 
-    with IngestHTTPServer(root, config=_serial_cfg()) as srv2:
+    # recovery runs in the constructor; pausing before start keeps the
+    # merger from draining the survivors before they are counted
+    srv2 = IngestHTTPServer(root, config=_serial_cfg())
+    srv2.pause()
+    with srv2:
         host, port = srv2.address
         with IngestClient(host, port) as c:
             m = c.metrics()
             assert m["spool_quarantined"] == 2
             assert m["pending"] == 3
+            srv2.resume()
             pub = c.publish()
     qdir = os.path.join(spool, QUARANTINE_DIR)
     assert sorted(os.listdir(qdir)) == [entries[1], "000000000099.rprf"]

@@ -1,7 +1,7 @@
 """Zero-copy data plane: fused phase-2 kernel, shm slab transport, mmap
-profile loads.  The central assertion everywhere: every path (fused vs
-legacy pipeline, shm vs pickle transport, all four executors) produces
-byte-identical databases."""
+profile loads.  The central assertion everywhere: every executor, with
+slabs or without, writes the bytes of the database that the references
+(the three-pass chain and the heap-merge CMS) write."""
 import hashlib
 import os
 import signal
@@ -17,14 +17,14 @@ from repro.core.aggregate import AggregationConfig, StreamingAggregator
 from repro.core.cct import ContextTree
 from repro.core.metrics import INCLUSIVE_BIT
 from repro.core.pipeline import fused_transform
-from repro.core.propagate import (propagate_inclusive,
-                                  propagate_inclusive_reference,
-                                  redistribute_placeholders)
+from repro.core.propagate import propagate_inclusive_reference
 from repro.core.sparse import MeasurementProfile, SparseMetrics
 from repro.runtime import SlabArena, get_executor
+from repro.runtime import shm as shm_mod
 from repro.runtime.shm import attach, sections_layout
 from repro.utils import binio
-from tests.conftest import make_profile
+from tests.conftest import (legacy_transform, make_profile, reference_run,
+                            shm_alive)
 
 
 def _digest(path):
@@ -78,11 +78,8 @@ def _random_tree_case(rng, max_nodes=60):
 def test_fused_transform_bytes_equal_legacy_chain(seed, keep_exclusive):
     rng = np.random.default_rng(seed)
     sm, remap, routes, parent_pre, end, n = _random_tree_case(rng)
-    legacy = sm.remap_contexts(remap)
-    if routes:
-        legacy = redistribute_placeholders(legacy, routes)
-    legacy = propagate_inclusive(legacy, np.arange(n), end,
-                                 keep_exclusive=keep_exclusive)
+    legacy = legacy_transform(sm, remap, routes, parent_pre, end,
+                              keep_exclusive=keep_exclusive)
     fused = fused_transform(sm, remap, routes, parent_pre, end,
                             keep_exclusive=keep_exclusive)
     assert legacy.encode() == fused.encode()
@@ -180,58 +177,89 @@ def test_encode_into_matches_encode(rng):
 
 
 # ---------------------------------------------------------------------------
-# engine parity: pipelines x transports x executors, one database
+# engine parity: executors x plane transports against the references
 # ---------------------------------------------------------------------------
 
 def test_parity_fused_vs_legacy_all_executors(tmp_path, rng):
+    """Every executor writes the database of the references: the three-pass
+    chain in phase 2 and the heap-merge CMS, run serially."""
     paths = _save_workload(tmp_path, rng)
-    digests = set()
-    results = []
+    ref = reference_run(tmp_path / "reference", paths)
+    want = (_digest(ref.cms_path), _digest(ref.trace_path), ref.n_contexts,
+            ref.n_values)
     for executor in ("serial", "threads", "processes", "ranks"):
-        for pipeline in ("fused", "legacy"):
-            cfg = AggregationConfig(executor=executor, n_workers=2,
-                                    n_threads=2, pipeline=pipeline)
-            res = StreamingAggregator(
-                tmp_path / f"{executor}_{pipeline}", cfg).run(paths)
-            results.append((executor, res))
-            # ranks PMS uses per-rank segment layout (query-identical);
-            # CMS + traces are byte-identical across all four
-            digests.add((_digest(res.cms_path), _digest(res.trace_path),
-                         res.n_contexts, res.n_values))
-    assert len(digests) == 1
-    stream_pms = {_digest(r.pms_path) for e, r in results if e != "ranks"}
-    assert len(stream_pms) == 1
+        cfg = AggregationConfig(executor=executor, n_workers=2, n_threads=2)
+        res = StreamingAggregator(tmp_path / executor, cfg).run(paths)
+        # ranks PMS uses per-rank segment layout (query-identical);
+        # CMS + traces are byte-identical across all four
+        assert (_digest(res.cms_path), _digest(res.trace_path),
+                res.n_contexts, res.n_values) == want, executor
+        if executor != "ranks":
+            assert _digest(res.pms_path) == _digest(ref.pms_path), executor
 
 
-def test_parity_shm_vs_pickle_transport(tmp_path, rng):
+def _no_slab_arena(*args, **kwargs):
+    raise OSError("no usable /dev/shm")
+
+
+def test_parity_shm_vs_pickle_transport(tmp_path, rng, monkeypatch):
+    """Planes inline in the result descriptors (no slab arena on the host),
+    through slabs, and through one-shot segments: one database."""
     paths = _save_workload(tmp_path, rng)
     digests = set()
-    for transport, slab in [("pickle", 1 << 20), ("shm", 1 << 20),
+    for transport, slab in [("inline", 1 << 20), ("shm", 1 << 20),
                             ("shm", 128)]:   # 128B forces one-shot fallback
-        cfg = AggregationConfig(executor="processes", n_workers=3,
-                                plane_transport=transport,
-                                shm_slab_bytes=slab)
-        res = StreamingAggregator(
-            tmp_path / f"t_{transport}_{slab}", cfg).run(paths)
+        with monkeypatch.context() as mp:
+            if transport == "inline":
+                mp.setattr(shm_mod, "SlabArena", _no_slab_arena)
+            cfg = AggregationConfig(executor="processes", n_workers=3,
+                                    shm_slab_bytes=slab)
+            res = StreamingAggregator(
+                tmp_path / f"t_{transport}_{slab}", cfg).run(paths)
         digests.add((_digest(res.pms_path), _digest(res.cms_path),
                      _digest(res.trace_path)))
     assert len(digests) == 1
 
 
+def test_sharded_run_without_slab_arena_ships_planes_inline(
+        tmp_path, rng, monkeypatch):
+    """Where the host cannot make a slab arena, the sharded run ships every
+    plane inline in its result descriptor and writes the bytes it writes
+    with slabs."""
+    import repro.core.aggregate as agg_mod
+    paths = _save_workload(tmp_path, rng, n=6)
+    cfg = AggregationConfig(executor="processes", n_workers=2)
+    slabs = StreamingAggregator(tmp_path / "slabs", cfg).run(paths)
+    kinds = []
+    open_result = agg_mod._open_plane_result
+
+    def recorded(item, arena):
+        kinds.append((item[0], arena))
+        return open_result(item, arena)
+
+    monkeypatch.setattr(shm_mod, "SlabArena", _no_slab_arena)
+    monkeypatch.setattr(agg_mod, "_open_plane_result", recorded)
+    inline = StreamingAggregator(tmp_path / "inline", cfg).run(paths)
+    assert kinds == [("raw", None)] * len(paths)
+    for a, b in [(slabs.pms_path, inline.pms_path),
+                 (slabs.cms_path, inline.cms_path),
+                 (slabs.trace_path, inline.trace_path)]:
+        assert _digest(a) == _digest(b)
+
+
 def test_parity_with_lexical_routes_fused(tmp_path):
-    """Superposition routes through the fused kernel: identical across
-    executors and identical to the legacy pipeline."""
+    """Superposition routes through the fused kernel: every executor writes
+    the references' database."""
     from tests.test_aggregate import _profile_with_structure
     ppath = _profile_with_structure(tmp_path, fused=True)
-    digests = set()
+    ref = reference_run(tmp_path / "lex_reference", [ppath])
+    want = (_digest(ref.pms_path), _digest(ref.cms_path))
     for executor in ("serial", "threads", "processes"):
-        for pipeline in ("fused", "legacy"):
-            cfg = AggregationConfig(executor=executor, n_workers=2,
-                                    pipeline=pipeline)
-            res = StreamingAggregator(
-                tmp_path / f"lex_{executor}_{pipeline}", cfg).run([ppath])
-            digests.add((_digest(res.pms_path), _digest(res.cms_path)))
-    assert len(digests) == 1
+        cfg = AggregationConfig(executor=executor, n_workers=2)
+        res = StreamingAggregator(
+            tmp_path / f"lex_{executor}", cfg).run([ppath])
+        assert (_digest(res.pms_path), _digest(res.cms_path)) == want, \
+            executor
 
 
 def test_sharded_sink_residency_bounded_by_window(tmp_path, rng):
@@ -248,26 +276,18 @@ def test_sharded_sink_residency_bounded_by_window(tmp_path, rng):
     assert _digest(res.cms_path) == _digest(base.cms_path)
 
 
-def test_sharded_unbounded_pickle_feed_still_works(tmp_path, rng):
-    """sink_window=0 ('unbounded') with the pickle transport keeps the
-    historical unthrottled feed — no slab scarcity, no credit gate."""
+def test_sharded_unbounded_pickle_feed_still_works(tmp_path, rng,
+                                                   monkeypatch):
+    """sink_window=0 ('unbounded') on the inline path (no slab arena) keeps
+    the historical unthrottled feed — no slab scarcity, no credit gate."""
     paths = _save_workload(tmp_path, rng, n=6)
-    cfg = AggregationConfig(executor="processes", n_workers=2, sink_window=0,
-                            plane_transport="pickle")
-    res = StreamingAggregator(tmp_path / "unb", cfg).run(paths)
     base = StreamingAggregator(
         tmp_path / "unb_base", AggregationConfig(executor="serial")).run(paths)
+    monkeypatch.setattr(shm_mod, "SlabArena", _no_slab_arena)
+    cfg = AggregationConfig(executor="processes", n_workers=2, sink_window=0)
+    res = StreamingAggregator(tmp_path / "unb", cfg).run(paths)
     assert _digest(res.pms_path) == _digest(base.pms_path)
     assert _digest(res.cms_path) == _digest(base.cms_path)
-
-
-def test_unknown_pipeline_and_transport_are_value_errors(tmp_path):
-    with pytest.raises(ValueError, match="pipeline"):
-        StreamingAggregator(tmp_path / "a", AggregationConfig(
-            pipeline="warp")).run([])
-    with pytest.raises(ValueError, match="plane_transport"):
-        StreamingAggregator(tmp_path / "b", AggregationConfig(
-            plane_transport="carrier-pigeon")).run([])
 
 
 # ---------------------------------------------------------------------------
@@ -328,24 +348,20 @@ def _kill_on_marker(task):
 
 @pytest.mark.skipif(sys.platform != "linux", reason="fork start method")
 def test_killed_worker_mid_slab_raises_and_cleans_up(tmp_path, rng,
-                                                     monkeypatch):
+                                                     monkeypatch, own_shm):
     """A worker SIGKILLed while owning a slab: the parent must raise (not
     hang waiting on the lost plane) and unlink the whole arena."""
     import repro.core.aggregate as agg_mod
     monkeypatch.setattr(agg_mod, "_phase2_profile_worker", _kill_on_marker)
     paths = _save_workload(tmp_path, rng, n=6)
-    before = {f for f in os.listdir("/dev/shm")} if os.path.isdir("/dev/shm") \
-        else set()
-    cfg = AggregationConfig(executor="processes", n_workers=2,
-                            plane_transport="shm")
+    cfg = AggregationConfig(executor="processes", n_workers=2)
     t0 = time.monotonic()
     with pytest.raises(Exception):
         StreamingAggregator(tmp_path / "killed", cfg).run(paths)
     assert time.monotonic() - t0 < 60
-    if os.path.isdir("/dev/shm"):
-        leaked = {f for f in os.listdir("/dev/shm")
-                  if f.startswith("psm_")} - before
-        assert not leaked
+    created = own_shm()
+    assert len(created) >= 4   # the arena: 2 x workers slabs
+    assert not shm_alive(created)
 
 
 def test_map_throttled_respects_credits():
@@ -622,10 +638,7 @@ def test_device_compute_falls_back_to_cpu_without_accelerator():
     assert cfg.compute == "device"
 
 
-def test_device_requires_fused_pipeline(tmp_path):
-    with pytest.raises(ValueError, match="fused"):
-        StreamingAggregator(tmp_path / "x", AggregationConfig(
-            compute="device", pipeline="legacy")).run([])
+def test_unknown_compute_is_value_error(tmp_path):
     with pytest.raises(ValueError, match="compute"):
         StreamingAggregator(tmp_path / "y", AggregationConfig(
             compute="quantum")).run([])

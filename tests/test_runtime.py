@@ -367,21 +367,31 @@ def test_async_streaming_reducer_empty_and_errors():
 
 
 def test_stats_merge_modes_byte_identical(tmp_path, rng):
-    """stats_merge=workers must not perturb a single output byte relative
-    to the inline fold — only where the merges run changes."""
+    """The pooled fold (more than one worker) writes the inline fold's
+    bytes (one worker) — only where the merges run changes."""
     paths = _save_workload(tmp_path, rng, n=7)
     digests = set()
-    for mode, executor in [("inline", "threads"), ("workers", "threads"),
-                           ("workers", "processes"), ("auto", "serial")]:
-        cfg = AggregationConfig(executor=executor, n_workers=2,
-                                stats_merge=mode)
+    for executor, workers in [("serial", 1), ("threads", 1), ("threads", 2),
+                              ("processes", 2)]:
+        cfg = AggregationConfig(executor=executor, n_workers=workers)
         res = StreamingAggregator(
-            tmp_path / f"sm_{mode}_{executor}", cfg).run(paths)
+            tmp_path / f"sm_{executor}_{workers}", cfg).run(paths)
         digests.add((_digest(res.pms_path), _digest(res.cms_path)))
     assert len(digests) == 1
 
 
-def test_invalid_stats_merge_is_value_error(tmp_path):
-    with pytest.raises(ValueError, match="stats_merge"):
-        StreamingAggregator(tmp_path / "x", AggregationConfig(
-            stats_merge="gpu")).run([])
+def test_stats_reducer_pooled_iff_more_than_one_worker(tmp_path, rng):
+    """The worker count chooses the fold: pooled merges at 4 workers, the
+    consume thread's at 1, and the same database bytes either way."""
+    from repro.core.aggregate import _make_stats_reducer
+    from repro.runtime.reduce import AsyncStreamingReducer, StreamingReducer
+    paths = _save_workload(tmp_path, rng, n=9)
+    digests = set()
+    for workers, kind in [(1, StreamingReducer), (4, AsyncStreamingReducer)]:
+        cfg = AggregationConfig(executor="threads", n_workers=workers)
+        reducer = _make_stats_reducer(cfg)
+        assert type(reducer) is kind
+        reducer.close()
+        res = StreamingAggregator(tmp_path / f"w{workers}", cfg).run(paths)
+        digests.add((_digest(res.pms_path), _digest(res.cms_path)))
+    assert len(digests) == 1

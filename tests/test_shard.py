@@ -19,7 +19,7 @@ from repro.serve.scheduler import BatchScheduler, Overloaded
 from repro.serve.shard import (ConsistentHashRing, ShardedQueryServer,
                                _merge_scatter)
 from repro.serve.warm import plan_warm
-from tests.conftest import make_profile
+from tests.conftest import make_profile, shm_alive
 
 N_PROFILES = 6
 
@@ -225,18 +225,11 @@ class _SleepKillServer(QueryServer):
         return super().submit(req)
 
 
-def _shm_entries():
-    if not os.path.isdir("/dev/shm"):
-        return set()
-    return {f for f in os.listdir("/dev/shm") if f.startswith("psm_")}
-
-
 @pytest.mark.skipif(not hasattr(signal, "SIGKILL"), reason="POSIX only")
-def test_sigkill_mid_batch_replays_to_respawned_worker(db_dir):
+def test_sigkill_mid_batch_replays_to_respawned_worker(db_dir, own_shm):
     """Kill the worker serving a batch: the supervisor respawns it,
     replays the unanswered requests, and every client future resolves
     with byte-correct results — a crash costs latency, never answers."""
-    before = _shm_entries()
     with Database(db_dir) as db:
         ref = [QueryServer(db).serve_one(QueryRequest(op="profile", pid=p))
                for p in range(N_PROFILES)]
@@ -265,7 +258,8 @@ def test_sigkill_mid_batch_replays_to_respawned_worker(db_dir):
         again = srv.serve_one(QueryRequest(op="profile", pid=2))
         _assert_bytes_equal(again, ref[2])
     time.sleep(0.2)
-    assert not (_shm_entries() - before), "worker death leaked /dev/shm"
+    assert own_shm(), "the server made no slabs"
+    assert not shm_alive(own_shm()), "worker death leaked /dev/shm"
 
 
 @pytest.mark.skipif(not hasattr(signal, "SIGKILL"), reason="POSIX only")
@@ -288,15 +282,17 @@ def test_poison_request_resolves_worker_lost_not_forever(db_dir):
             srv.serve_one(QueryRequest(op="profile", pid=1)), ref)
 
 
-def test_close_unlinks_all_slabs(db_dir):
-    before = _shm_entries()
+def test_close_unlinks_all_slabs(db_dir, own_shm):
     srv = ShardedQueryServer(db_dir, 3, n_slabs=4, slab_bytes=1 << 16)
     srv.start()
-    assert len(_shm_entries() - before) == 12   # 3 shards x 4 slabs
+    created = own_shm()
+    assert len(created) == 12                   # 3 shards x 4 slabs
+    assert shm_alive(created) == set(created)
     srv.serve([QueryRequest(op="profile", pid=0)])
     srv.close()
     time.sleep(0.2)
-    assert not (_shm_entries() - before), "close() left shm segments"
+    assert own_shm() == created
+    assert not shm_alive(created), "close() left shm segments"
     srv.close()  # idempotent
 
 

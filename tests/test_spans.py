@@ -20,7 +20,7 @@ from repro.core.metrics import INCLUSIVE_BIT
 from repro.core.pms import PMSReader
 from repro.core.sparse import MeasurementProfile, SparseMetrics
 from repro.core.timer import PhaseTimer
-from tests.conftest import make_profile
+from tests.conftest import make_profile, reference_run
 
 
 class FakeClock:
@@ -334,14 +334,15 @@ def test_route_span_leaves_the_database_as_it_was(tmp_path, rng, compute,
 def test_structured_fleet_device_bytes_equal_cpu(tmp_path, rng):
     """Integer values and dyadic route weights keep every split value and
     sum exact in float32: the device path (interpret mode) writes the CPU
-    path's bytes, and both the legacy chain's (remap, then
-    ``redistribute_placeholders``, then ``propagate_inclusive``)."""
+    path's bytes, and both the references' (remap, then
+    ``redistribute_placeholders``, then ``propagate_inclusive``; the
+    heap-merge CMS)."""
     paths, _ = _structured_fleet(tmp_path, rng, exact=True)
     dbs = {name: _db_bytes(StreamingAggregator(tmp_path / name, cfg).run(
         paths)) for name, cfg in [
             ("device", _config("device", executor="threads", n_workers=3)),
-            ("cpu", _config("cpu", executor="threads", n_workers=3)),
-            ("legacy", _config("cpu", pipeline="legacy"))]}
+            ("cpu", _config("cpu", executor="threads", n_workers=3))]}
+    dbs["legacy"] = _db_bytes(reference_run(tmp_path / "legacy", paths))
     assert dbs["device"] == dbs["cpu"] == dbs["legacy"]
 
 
@@ -350,10 +351,11 @@ def test_cms_digest_equals_the_heap_merge(tmp_path, rng, executor):
     """On a structured fleet, every executor's vectorised CMS is the heap
     merge's bytes, and ``cms_planes`` counts each context with data."""
     paths, _ = _structured_fleet(tmp_path, rng)
-    runs = {strategy: StreamingAggregator(tmp_path / strategy, _config(
+    runs = {"vectorized": StreamingAggregator(tmp_path / "vectorized", _config(
         "cpu", executor=executor, n_workers=3, cms_workers=3,
-        group_target_bytes=2048, cms_strategy=strategy)).run(paths)
-        for strategy in ("vectorized", "heap")}
+        group_target_bytes=2048)).run(paths),
+        "heap": reference_run(tmp_path / "heap", paths, cms_workers=3,
+                              group_target_bytes=2048)}
     digests = {strategy: hashlib.sha256(open(res.cms_path, "rb").read()
                                         ).hexdigest()
                for strategy, res in runs.items()}
@@ -377,9 +379,9 @@ def test_structured_fleet_agrees_with_the_legacy_chain(tmp_path, seed):
     rng = np.random.default_rng(seed)
     paths, _ = _structured_fleet(tmp_path, rng)
     runs = {name: StreamingAggregator(tmp_path / name, cfg).run(paths)
-            for name, cfg in [("legacy", _config("cpu", pipeline="legacy")),
-                              ("cpu", _config("cpu")),
+            for name, cfg in [("cpu", _config("cpu")),
                               ("device", _config("device"))]}
+    runs["legacy"] = reference_run(tmp_path / "legacy", paths)
     assert _db_bytes(runs["cpu"]) == _db_bytes(runs["legacy"])
     for want, got in zip(_planes(runs["legacy"]), _planes(runs["device"])):
         w = {(c, m): v for c, m, v in zip(*(a.tolist()
